@@ -20,9 +20,10 @@ grid explicit:
     bit-exact-gated fallback)
 
 * **Per-cell caching**: results land in ``benchmarks/.matrix_cache/``
-  keyed by git rev + cell, so re-running a 12-cell matrix after an
-  unrelated edit only re-measures what the rev change invalidated
-  (``--no-cache`` forces fresh measurements).
+  keyed by a content hash of the ``.py`` files under ``src/`` and
+  ``benchmarks/`` + cell, so a cell is reused only by the exact code that
+  measured it, in a git checkout or not (``--no-cache`` forces fresh
+  measurements).
 
 * ``--record`` appends a timestamped entry (git rev + UTC date + args +
   every cell row) to the ``BENCH_matrix.json`` trajectory — append-only,
@@ -40,9 +41,11 @@ CSV rows: ``matrix,<cell>,<us_per_token>,<derived>``.
 from __future__ import annotations
 
 import dataclasses
+import hashlib
 import json
 import os
 import time
+from pathlib import Path
 
 import numpy as np
 
@@ -51,8 +54,9 @@ from repro.configs.base import ServeConfig
 from repro.models import lm
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(_HERE)
 CACHE_DIR = os.path.join(_HERE, ".matrix_cache")
-DEFAULT_TRAJECTORY = os.path.join(os.path.dirname(_HERE), "BENCH_matrix.json")
+DEFAULT_TRAJECTORY = os.path.join(ROOT, "BENCH_matrix.json")
 
 
 # --------------------------------------------------------------- cells --
@@ -199,20 +203,32 @@ def _git_rev() -> str:
     return rev()
 
 
-def _cache_path(rev: str, cell: Cell) -> str:
-    return os.path.join(CACHE_DIR, rev, cell.key.replace("/", "__") + ".json")
+def code_hash(root: str = ROOT) -> str:
+    """Content hash of every ``.py`` file under ``src/`` and
+    ``benchmarks/`` of the checkout at ``root`` (paths and bytes)."""
+    h = hashlib.sha256()
+    base = Path(root)
+    for sub in ("src", "benchmarks"):
+        for path in sorted((base / sub).rglob("*.py")):
+            h.update(str(path.relative_to(base)).encode() + b"\0")
+            h.update(path.read_bytes())
+    return h.hexdigest()[:16]
+
+
+def _cache_path(key: str, cell: Cell) -> str:
+    return os.path.join(CACHE_DIR, key, cell.key.replace("/", "__") + ".json")
 
 
 def run_cells(cells: tuple[Cell, ...], *, use_cache: bool = True,
               verbose: bool = False) -> list[dict]:
-    """Measure every cell, reading/writing the per-rev disk cache.  A
-    cached cell is a measurement taken at this exact git rev — safe to
-    reuse; any code change moves the rev and invalidates it."""
-    rev = _git_rev()
+    """Measure every cell, reading/writing the per-code-hash disk cache.
+    A cached cell is a measurement taken by this exact code — safe to
+    reuse; any edit to a source file moves the hash and invalidates it."""
+    key = code_hash()
     results = []
     for cell in cells:
-        path = _cache_path(rev, cell)
-        if use_cache and rev != "unknown" and os.path.exists(path):
+        path = _cache_path(key, cell)
+        if use_cache and os.path.exists(path):
             with open(path) as f:
                 rec = json.load(f)
             rec["cached"] = True
@@ -221,7 +237,7 @@ def run_cells(cells: tuple[Cell, ...], *, use_cache: bool = True,
                 print(f"# measuring {cell.key} ...")
             rec = measure_cell(cell)
             rec["cached"] = False
-            if use_cache and rev != "unknown":
+            if use_cache:
                 os.makedirs(os.path.dirname(path), exist_ok=True)
                 with open(path, "w") as f:
                     json.dump(rec, f, indent=2)
@@ -313,7 +329,7 @@ def main():
                     help="which cell set to run (smoke = 2-cell CI "
                          "tripwire, default = the full ablation grid)")
     ap.add_argument("--no-cache", action="store_true",
-                    help="ignore the per-rev cell cache and re-measure")
+                    help="ignore the per-code-hash cell cache and re-measure")
     ap.add_argument("--record", nargs="?", const=DEFAULT_TRAJECTORY,
                     default=None, metavar="PATH",
                     help="append this run to the trajectory JSON "

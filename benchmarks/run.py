@@ -27,6 +27,9 @@ def main() -> None:
         roofline_table,
         serving_throughput,
     )
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     benches = [
         ("latency_tables", latency_tables.run),

@@ -17,6 +17,7 @@ import jax
 import numpy as np
 
 from repro import configs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import lm
 from repro.serve import Engine
 from repro.serve.cli import add_serving_args, config_from_args
@@ -56,6 +57,7 @@ def main():
                      temperature=0.7)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = configs.get_config(args.arch, reduced=True)
     params = lm.init_params(cfg, jax.random.PRNGKey(0))
     eng = Engine(cfg, params, config_from_args(args, cfg))

@@ -61,7 +61,7 @@ def streaming_mha(
     window: int | None = None,
     softmax_mode: str = "lut",  # the paper's default datapath
     use_pallas_attention: bool = False,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     b, s, d_model = x.shape
     d_head = params.wq.shape[1] // n_heads

@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 PyTree = Any
 
@@ -76,7 +75,7 @@ def make_compressed_grad_allreduce(mesh: Mesh, axis_name="data"):
         flat, treedef = jax.tree.flatten(grads)
         eflat, _ = jax.tree.flatten(errors)
         outs = []
-        f = shard_map(
+        f = jax.shard_map(
             lambda g, e: compressed_psum_leaf(g, axis_name, e),
             mesh=mesh,
             in_specs=(spec, spec),
@@ -136,21 +135,17 @@ def ring_collective_matmul(
             return acc, w_nxt
 
         acc = jnp.zeros((x_local.shape[0], w_local.shape[1]), x_local.dtype)
-        # the carry becomes device-varying over `axis` inside the loop;
-        # older JAX lines have no varying-type system (and no lax.pcast) —
-        # there the unannotated carry is already fine under check_rep=False
-        pcast = getattr(jax.lax, "pcast", None)
-        if pcast is not None:
-            acc = pcast(acc, (axis,), to="varying")
+        # the carry becomes device-varying over `axis` inside the loop
+        acc = jax.lax.pcast(acc, (axis,), to="varying")
         acc, _ = jax.lax.fori_loop(0, n_shards, step, (acc, w_local))
         return acc
 
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(None, None), P(axis, None)),
         out_specs=P(None, None),
         # every device ends with the identical full product; skip the
         # replication check (classic manual-collective pattern)
-        check_rep=False,
+        check_vma=False,
     )(x, w)

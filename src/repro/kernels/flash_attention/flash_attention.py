@@ -33,9 +33,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
-
 from repro.core import lut
+from repro.kernels import lut_read
 
 NEG_INF = -1e30
 
@@ -51,7 +50,8 @@ def _make_kernel(
     kv_len: int,
 ):
     def _kernel(
-        q_ref, k_ref, v_ref, exp_tab_ref, inv_tab_ref, o_ref, m_ref, l_ref, acc_ref
+        q_ref, k_ref, v_ref, exp_tab_ref, inv_tab_ref, o_ref, m_ref, l_ref,
+        acc_ref, *lut_scratch,
     ):
         kv_idx = pl.program_id(2)
         n_kv = pl.num_programs(2)
@@ -102,17 +102,10 @@ def _make_kernel(
                 preferred_element_type=jnp.float32,
             )
         else:  # paper's LUT mode: bounded domain, no max subtraction
-            spec = lut.EXP_SPEC
-            idx = lut.lut_index(s, spec)
-            onehot = (
-                idx.reshape(-1)[:, None]
-                == jax.lax.iota(jnp.int32, spec.size)[None, :]
-            ).astype(jnp.float32)
-            p = jax.lax.dot_general(
-                onehot, exp_tab_ref[...],
-                dimension_numbers=(((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ).reshape(s.shape)
+            idx_ref, p_ref = lut_scratch
+            idx_ref[...] = lut.lut_index(s, lut.EXP_SPEC)
+            lut_read.read_rows(idx_ref, exp_tab_ref[...], p_ref)
+            p = p_ref[...]
             p = jnp.where(mask, p, 0.0)
             l_ref[...] += jnp.sum(p, axis=-1, keepdims=True)
             acc_ref[...] += jax.lax.dot_general(
@@ -127,17 +120,9 @@ def _make_kernel(
                 inv = jnp.where(l > 0, 1.0 / jnp.maximum(l, 1e-30), 0.0)
             else:
                 # paper stage 2: denominator reciprocal via the inversion LUT
-                ispec = lut.INV_SPEC
-                iidx = lut.lut_index(l, ispec)
-                ioneh = (
-                    iidx.reshape(-1)[:, None]
-                    == jax.lax.iota(jnp.int32, ispec.size)[None, :]
-                ).astype(jnp.float32)
-                inv = jax.lax.dot_general(
-                    ioneh, inv_tab_ref[...],
-                    dimension_numbers=(((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                ).reshape(l.shape)
+                inv = lut_read.read_column(
+                    lut.lut_index(l, lut.INV_SPEC), inv_tab_ref[...]
+                )
                 inv = jnp.where(l > 0, inv, 0.0)
             o_ref[0] = (acc_ref[...] * inv).astype(o_ref.dtype)
 
@@ -172,8 +157,12 @@ def flash_attention_pallas(
     block_kv = min(block_kv, lkv)
     assert lq % block_q == 0 and lkv % block_kv == 0, (lq, lkv, block_q, block_kv)
     grid = (bh, lq // block_q, lkv // block_kv)
-    exp_tab = lut.exp_table().reshape(-1, 1)
-    inv_tab = lut.inv_table().reshape(-1, 1)
+    exp_tab = lut.exp_table().reshape(1, -1)  # row: read_rows
+    inv_tab = lut.inv_table().reshape(-1, 1)  # column: read_column
+    lut_scratch = [
+        pltpu.VMEM((block_q, block_kv), jnp.int32),  # exp-table indices
+        pltpu.VMEM((block_q, block_kv), jnp.float32),  # exp-table values
+    ] if mode == "lut" else []
     kernel = _make_kernel(
         scale=scale, causal=causal, window=window, mode=mode,
         block_q=block_q, block_kv=block_kv, kv_len=kv_len,
@@ -194,8 +183,8 @@ def flash_attention_pallas(
             pltpu.VMEM((block_q, 1), jnp.float32),  # running max m
             pltpu.VMEM((block_q, 1), jnp.float32),  # running sum l
             pltpu.VMEM((block_q, d), jnp.float32),  # output accumulator
-        ],
-        compiler_params=tpu_compiler_params(
+        ] + lut_scratch,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

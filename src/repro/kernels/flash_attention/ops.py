@@ -42,7 +42,7 @@ def mha(
     window: int | None = None,
     mode: str = "safe",
     use_pallas: bool = False,
-    interpret: bool = True,
+    interpret: bool = False,
     block_q: int = 128,
     block_kv: int = 128,
 ) -> jax.Array:

@@ -23,9 +23,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
-
 from repro.core import lut
+from repro.kernels import lut_read
 
 
 def _make_kernel(use_lut: bool, rms: bool, eps: float):
@@ -39,18 +38,9 @@ def _make_kernel(use_lut: bool, rms: bool, eps: float):
             dm = x - mean  # stage 2
         var = jnp.sum(dm * dm, axis=-1, keepdims=True) / k  # stage 3
         if use_lut:  # stage 4 via LUT (one-hot MXU read)
-            spec = lut.RSQRT_SPEC
-            idx = lut.lut_index(var, spec)
-            onehot = (
-                idx.reshape(-1)[:, None]
-                == jax.lax.iota(jnp.int32, spec.size)[None, :]
-            ).astype(jnp.float32)
-            inv_std = jax.lax.dot_general(
-                onehot,
-                rsqrt_tab_ref[...],
-                dimension_numbers=(((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ).reshape(var.shape)
+            inv_std = lut_read.read_column(
+                lut.lut_index(var, lut.RSQRT_SPEC), rsqrt_tab_ref[...]
+            )
         else:
             inv_std = jax.lax.rsqrt(var + eps)
         x_hat = dm * inv_std
@@ -92,7 +82,7 @@ def layernorm_pallas(
         ],
         out_specs=pl.BlockSpec((block_rows, k), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, k), x.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,

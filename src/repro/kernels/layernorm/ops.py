@@ -36,7 +36,7 @@ def layernorm(
     rms: bool = False,
     eps: float = 1e-5,
     use_pallas: bool = True,
-    interpret: bool = True,
+    interpret: bool = False,
     precision=None,  # core.precision.Precision (fixed): output grid
 ) -> jax.Array:
     k = x.shape[-1]

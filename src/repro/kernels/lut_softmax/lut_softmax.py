@@ -26,39 +26,18 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
-
 from repro.core import lut
+from repro.kernels import lut_read
 
 
-def _lut_gather_mxu(x, table_ref, spec):
-    """one_hot(lut_index(x)) @ table — MXU-native LUT read.
-
-    Index arithmetic comes from ``core.lut.lut_index`` (pure jnp, valid in
-    kernel bodies): linear for the paper's fixed-point exp table, log-
-    spaced for the reciprocal family (see LutSpec docstring).
-    """
-    idx = lut.lut_index(x, spec)
-    flat = idx.reshape(-1)
-    onehot = (
-        flat[:, None] == jax.lax.iota(jnp.int32, spec.size)[None, :]
-    ).astype(table_ref.dtype)
-    vals = jax.lax.dot_general(
-        onehot,
-        table_ref[...],
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    return vals.reshape(x.shape)
-
-
-def _lut_softmax_kernel(x_ref, exp_tab_ref, inv_tab_ref, o_ref):
-    x = x_ref[...].astype(jnp.float32)
+def _lut_softmax_kernel(x_ref, exp_tab_ref, inv_tab_ref, o_ref, idx_ref, e_ref):
     # Stage 1: exp LUT (element-wise).
-    e = _lut_gather_mxu(x, exp_tab_ref, lut.EXP_SPEC)
+    idx_ref[...] = lut.lut_index(x_ref[...].astype(jnp.float32), lut.EXP_SPEC)
+    lut_read.read_rows(idx_ref, exp_tab_ref[...], e_ref)
+    e = e_ref[...]
     # Stage 2: row sum, then inversion LUT (once per row).
     s = jnp.sum(e, axis=-1, keepdims=True)
-    inv = _lut_gather_mxu(s, inv_tab_ref, lut.INV_SPEC)
+    inv = lut_read.read_column(lut.lut_index(s, lut.INV_SPEC), inv_tab_ref[...])
     # Stage 3: element-wise multiply.
     o_ref[...] = (e * inv).astype(o_ref.dtype)
 
@@ -66,8 +45,8 @@ def _lut_softmax_kernel(x_ref, exp_tab_ref, inv_tab_ref, o_ref):
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def lut_softmax_pallas(
     x: jax.Array,  # (R, K) scores
-    exp_table: jax.Array,  # (T_exp, 1)
-    inv_table: jax.Array,  # (T_inv, 1)
+    exp_table: jax.Array,  # (1, T_exp) row
+    inv_table: jax.Array,  # (T_inv, 1) column
     *,
     block_rows: int = 64,
     interpret: bool = False,
@@ -85,7 +64,11 @@ def lut_softmax_pallas(
         ],
         out_specs=pl.BlockSpec((block_rows, k), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, k), x.dtype),
-        compiler_params=tpu_compiler_params(
+        scratch_shapes=[
+            pltpu.VMEM((block_rows, k), jnp.int32),  # exp-table indices
+            pltpu.VMEM((block_rows, k), jnp.float32),  # exp-table values
+        ],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,

@@ -28,7 +28,7 @@ def lut_softmax(
     x: jax.Array,
     *,
     use_pallas: bool = True,
-    interpret: bool = True,
+    interpret: bool = False,
     precision=None,  # core.precision.Precision (fixed): output grid
 ) -> jax.Array:
     """Softmax over the last axis via the paper's 3-stage LUT dataflow.
@@ -47,7 +47,7 @@ def lut_softmax(
     block_rows = min(64, rows) if rows % 64 != 0 else 64
     while rows % block_rows != 0:
         block_rows -= 1
-    exp_tab = lut.exp_table().reshape(-1, 1)
+    exp_tab = lut.exp_table().reshape(1, -1)
     inv_tab = lut.inv_table().reshape(-1, 1)
     out = lut_softmax_pallas(
         x2, exp_tab, inv_tab, block_rows=block_rows, interpret=interpret

@@ -39,7 +39,7 @@ def qmatmul(
     reuse_factor: int = 1,
     strategy: reuse.Strategy = reuse.Strategy.LATENCY,
     use_pallas: bool = True,
-    interpret: bool = True,
+    interpret: bool = False,
     precision=None,  # core.precision.Precision (int8 kind): bits/granularity
 ) -> jax.Array:
     """Quantize x (per-row) and w (per-col) to int8 and multiply.

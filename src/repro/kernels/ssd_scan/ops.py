@@ -21,7 +21,7 @@ def ssd(
     *,
     chunk: int = 64,
     use_pallas: bool = True,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     b, l, h, p = xdt.shape
     n = bmat.shape[-1]

@@ -26,8 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
-
 
 def _ssd_chunk_kernel(xdt_ref, a_ref, b_ref, c_ref, y_ref, state_ref):
     ci = pl.program_id(1)
@@ -109,7 +107,7 @@ def ssd_scan_pallas(
         out_specs=pl.BlockSpec((1, chunk, p), lambda b, c: (b, c, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, l, p), xdt.dtype),
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

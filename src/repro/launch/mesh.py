@@ -11,18 +11,10 @@ import jax
 
 
 def make_mesh(shape, axes):
-    """Version-tolerant ``jax.make_mesh``.
-
-    ``jax.sharding.AxisType`` (and the ``axis_types`` kwarg) only exist on
-    newer JAX lines; on older ones every axis is implicitly Auto, which is
-    exactly what we want — so pass it only when available.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(
-            shape, axes, axis_types=(axis_type.Auto,) * len(axes)
-        )
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis Auto (sharding by propagation)."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -3,6 +3,11 @@ quantized datapath, fed from a simple request file or synthetic load.
 
     PYTHONPATH=src python -m repro.launch.serve --arch granite-8b --quantized
 
+Without ``--full-config`` the arch runs at its reduced smoke shape.  With
+it the default ``granite-8b`` is about 8.2 B parameters (16.5 GB in
+bf16) and does not fit one TPU v5e's 16 GB of HBM; ``minicpm-2b`` does
+(see ``chip_smoke.py`` for a pool size that fits beside it).
+
 All engine flags come from the shared serving CLI (serve/cli.py);
 ``--stream`` switches from the batch ``Engine.generate`` wrapper to
 per-token ``Engine.stream`` consumption and reports time-to-first-token.
@@ -17,6 +22,7 @@ import jax
 import numpy as np
 
 from repro import configs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import lm
 from repro.serve import Engine, ReplicaRouter
 from repro.serve.cli import (  # noqa: F401  (resolve_policy_arg re-export)
@@ -35,6 +41,7 @@ def main():
                      temperature=0.0)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = configs.get_config(args.arch, reduced=not args.full_config)
     params = lm.init_params(cfg, jax.random.PRNGKey(0))
     serve_cfg = config_from_args(args, cfg)

@@ -161,7 +161,7 @@ def gqa_apply(
             window=window,
             mode=kernel.get("softmax_mode", "safe"),
             use_pallas=kernel.get("use_pallas", False),
-            interpret=kernel.get("interpret", True),
+            interpret=kernel.get("interpret", False),
         )
     elif mode == "prefill":
         if kv_cache_lib.is_paged(cache):
@@ -235,7 +235,7 @@ def gqa_apply(
             window=window,
             mode=kernel.get("softmax_mode", "safe"),
             use_pallas=kernel.get("use_pallas", False),
-            interpret=kernel.get("interpret", True),
+            interpret=kernel.get("interpret", False),
         )
     elif mode == "extend":
         # cache-extending prefill: W window tokens per row written at
@@ -643,7 +643,7 @@ def mla_apply(
             causal=not cfg.is_encoder,
             mode=kernel.get("softmax_mode", "safe"),
             use_pallas=kernel.get("use_pallas", False),
-            interpret=kernel.get("interpret", True),
+            interpret=kernel.get("interpret", False),
         )[..., :vd]
         out = _merge_heads(out)
 

@@ -383,12 +383,17 @@ class ModelExecutor:
         # dequantized codes), and LUT softmax (decode uses exact softmax).
         # The scheduler gates prefill-skip, preemption-resume, and chunked
         # prefill on this capability so token streams stay bit-identical
-        # to dense.
+        # to dense.  The identity holds on the CPU backend only: on a TPU
+        # the one-token decode attention rounds differently from prefill's
+        # (a resumed seeded stream on minicpm-2b drew another token), while
+        # the extend program matches prefill bit for bit, so there every
+        # datapath replays through extend.
         self.bit_exact = (
             cfg.attn_kind == "gqa"
             and not self.quant_cache
             and self.kernel.get("softmax_mode", "safe") == "safe"
             and not self.kernel.get("use_pallas", False)
+            and jax.default_backend() == "cpu"
         )
 
         # right-padding the prompt is only sound when the cache is

@@ -281,7 +281,7 @@ class ExecutorCaps:
     bucketable: bool  # position-addressed cache: right-padding is sound
     paged: bool  # block-table page pool (vs dense slot slabs)
     #: decode-path forward bitwise identical to prefill-path forward
-    #: (float GQA, exact softmax, reference kernel) — lets prompt
+    #: (float GQA, exact softmax, reference kernel, CPU backend) — lets prompt
     #: positions replay through the decode scan
     bit_exact: bool
     prefix_cache: bool  # prefix index live (paged + kv_prefix_cache)

@@ -146,6 +146,29 @@ def test_preemption_resume_parity(arch, policy):
     eng.executor.cache_mgr.check_invariants()
 
 
+def test_float_gqa_on_tpu_resumes_with_prefill_math(monkeypatch):
+    """On a TPU the decode scan is not bitwise the prefill, so a float
+    GQA engine built there replays a preemption resume's prompt span with
+    prefill math and only its generated span through the decode scan —
+    and still matches the dense engine."""
+    cfg, params = _setup("granite-8b")
+    prompts = ([7, 8, 9], [1, 2, 3])
+    kw = dict(max_seq_len=32)
+    _, dense = _gen(cfg, params, _serve(None, **kw), prompts, n_new=20)
+    with monkeypatch.context() as m:
+        m.setattr(jax, "default_backend", lambda: "tpu")
+        eng = Engine(cfg, params, _serve(
+            None, kv_layout="paged", kv_page_size=8, kv_pages=5,
+            kv_preemption=True, kv_prefix_cache=True, **kw,
+        ))
+    assert not eng.executor.bit_exact and eng.scheduler.extend_replay
+    handles = [eng.submit(list(p), max_new_tokens=20) for p in prompts]
+    res = eng.generate()
+    assert [res[h.uid].generated for h in handles] == dense
+    assert eng.telemetry["preemptions"] >= 1
+    eng.executor.cache_mgr.check_invariants()
+
+
 def test_jit_program_budget_with_extend():
     """The one new program is ONE program: with chunking, prefix sharing
     and preemption all on, on an extend datapath (MLA), the jit caches

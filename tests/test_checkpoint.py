@@ -8,7 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.launch.mesh import make_mesh as make_compat_mesh
+from repro.launch.mesh import make_mesh
 from repro.checkpoint import Checkpointer
 
 
@@ -92,7 +92,7 @@ def test_elastic_restore_with_shardings(tmp_path):
     ckpt = Checkpointer(str(tmp_path), keep=2)
     tree = _tree()
     ckpt.save(1, tree, blocking=True)
-    mesh = make_compat_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     sh = NamedSharding(mesh, P())
     shardings = {
         "params": {"w": sh, "b": sh},

@@ -12,7 +12,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro import configs
-from repro.launch.mesh import make_mesh as make_compat_mesh
+from repro.launch.mesh import make_mesh
 from repro.configs.base import ParallelismConfig
 from repro.distributed.sharding import ShardingRules
 
@@ -22,7 +22,7 @@ ENV = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
 
 @pytest.fixture(scope="module")
 def rules():
-    mesh = make_compat_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     return ShardingRules(mesh=mesh, plan=ParallelismConfig())
 
 
@@ -34,7 +34,7 @@ def test_spec_for_divisibility_fallback(rules):
 
 
 def test_spec_rank_matches():
-    mesh = make_compat_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     r = ShardingRules(mesh=mesh)
     spec = r.spec_for(("layers", "embed", "mlp"), (4, 32, 64))
     assert len(spec) == 3
@@ -73,8 +73,8 @@ def test_ring_collective_matmul_multidevice():
         """
 import jax, jax.numpy as jnp, numpy as np
 from repro.distributed.collectives import ring_collective_matmul
-from repro.launch.mesh import make_mesh as make_compat_mesh
-mesh = make_compat_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 rng = np.random.default_rng(0)
 x = jnp.asarray(rng.normal(size=(16, 32)), jnp.float32)
 w = jnp.asarray(rng.normal(size=(32, 24)), jnp.float32)
@@ -93,8 +93,8 @@ def test_compressed_allreduce_error_feedback_converges():
         """
 import jax, jax.numpy as jnp, numpy as np
 from repro.distributed.collectives import make_compressed_grad_allreduce, init_error_buffers
-from repro.launch.mesh import make_mesh as make_compat_mesh
-mesh = make_compat_mesh((8,), ("data",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ("data",))
 g = {"w": jnp.asarray(np.random.default_rng(0).normal(size=(32, 32)), jnp.float32)}
 err = init_error_buffers(g)
 f = make_compressed_grad_allreduce(mesh, axis_name="data")

@@ -18,7 +18,7 @@ except ImportError:  # minimal images: deterministic fallback shim
     from _hypothesis_shim import given, settings, st
 from jax.sharding import PartitionSpec as P
 
-from repro.launch.mesh import make_mesh as make_compat_mesh
+from repro.launch.mesh import make_mesh
 from repro.configs.base import ParallelismConfig
 from repro.distributed.sharding import ShardingRules
 
@@ -33,7 +33,7 @@ def mesh():
     # 8 forced host devices are NOT available under the normal test
     # process (1 device) — use a 1x1 mesh for structural properties and
     # rely on tests/test_distributed.py subprocesses for multi-device.
-    return make_compat_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 @settings(max_examples=100, deadline=None)
@@ -42,7 +42,7 @@ def mesh():
     st.lists(st.integers(1, 512), min_size=1, max_size=4),
 )
 def test_spec_is_always_valid(axes, dims):
-    mesh = make_compat_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     n = min(len(axes), len(dims))
     axes, dims = tuple(axes[:n]), tuple(dims[:n])
     rules = ShardingRules(mesh=mesh, plan=ParallelismConfig())
@@ -73,8 +73,8 @@ os.environ['XLA_FLAGS'] = '--xla_force_host_platform_device_count=16'
 import jax
 from repro.configs.base import ParallelismConfig
 from repro.distributed.sharding import ShardingRules
-from repro.launch.mesh import make_mesh as make_compat_mesh
-mesh = make_compat_mesh((1, 16), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((1, 16), ("data", "model"))
 rules = ShardingRules(mesh=mesh, plan=ParallelismConfig())
 spec = rules.spec_for(("experts", "embed", "mlp"), (40, 64, 512))
 assert spec[0] is None, spec           # 40 % 16 != 0 -> replicated
@@ -96,7 +96,7 @@ print("OK")
 @settings(max_examples=50, deadline=None)
 @given(st.integers(1, 4), st.integers(1, 1024))
 def test_batch_spec_shape_fallback(ndim, batch):
-    mesh = make_compat_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     rules = ShardingRules(mesh=mesh, plan=ParallelismConfig())
     shape = (batch,) + (8,) * (ndim - 1)
     spec = rules.batch_spec(ndim, shape=shape)

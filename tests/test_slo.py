@@ -463,6 +463,37 @@ def test_matrix_cells_and_trajectory(tmp_path):
     assert "cached" not in history[0]["cells"][0]
 
 
+def test_matrix_cache_keyed_on_source_hash(tmp_path, monkeypatch):
+    from benchmarks import matrix
+
+    # the key follows file contents, not a git rev
+    for sub in ("src", "benchmarks"):
+        (tmp_path / sub).mkdir()
+        (tmp_path / sub / "m.py").write_text("x = 1\n")
+    (tmp_path / "notes.txt").write_text("not code")
+    before = matrix.code_hash(str(tmp_path))
+    assert matrix.code_hash(str(tmp_path)) == before
+    (tmp_path / "notes.txt").write_text("still not code")
+    assert matrix.code_hash(str(tmp_path)) == before
+    (tmp_path / "src" / "m.py").write_text("x = 2\n")
+    assert matrix.code_hash(str(tmp_path)) != before
+
+    # a cell cached under one hash is reused by that hash only
+    measured = []
+    monkeypatch.setattr(matrix, "CACHE_DIR", str(tmp_path / "cache"))
+    monkeypatch.setattr(
+        matrix, "measure_cell",
+        lambda cell: measured.append(cell) or {"cell": cell.key},
+    )
+    cells = (matrix.Cell(),)
+    monkeypatch.setattr(matrix, "code_hash", lambda: "aaaa")
+    assert [r["cached"] for r in matrix.run_cells(cells)] == [False]
+    assert [r["cached"] for r in matrix.run_cells(cells)] == [True]
+    monkeypatch.setattr(matrix, "code_hash", lambda: "bbbb")
+    assert [r["cached"] for r in matrix.run_cells(cells)] == [False]
+    assert len(measured) == 2
+
+
 def test_serving_trajectory_migrates_legacy_dict(tmp_path):
     import json
 
