@@ -1,0 +1,4 @@
+"""Device: 1 minus the union of device operation intervals over the traced
+window, in % (latency cells)."""
+
+from bench.readers import idle_share as read  # noqa: F401

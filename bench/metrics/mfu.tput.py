@@ -1,0 +1,5 @@
+"""Whole step: model FLOPs of the prompt and output tokens processed in the
+traced window over its seconds times the chip's bf16 peak, in %
+(throughput cells)."""
+
+from bench.readers import mfu as read  # noqa: F401
