@@ -435,19 +435,18 @@ class Engine:
         if self.executor.async_loop:
             return self._step_async()
         tr = self._tracer
-        tr.begin_step()
-        with tr.phase("schedule"):
-            decision = self.scheduler.schedule(self.executor.slots)
-        out = self.executor.execute(decision)
-        now = self.clock()
-        self._route_output(out, now)
-        self._route_dropped(decision.dropped, now)
-        stats = out.stats
-        stats.update(
-            prefill_compiles=self.executor.tel["prefill_compiles"],
-            decode_compiles=self.executor.tel["decode_compiles"],
-        )
-        tr.end_step()
+        with tr.step():
+            with tr.phase("schedule"):
+                decision = self.scheduler.schedule(self.executor.slots)
+            out = self.executor.execute(decision)
+            now = self.clock()
+            self._route_output(out, now)
+            self._route_dropped(decision.dropped, now)
+            stats = out.stats
+            stats.update(
+                prefill_compiles=self.executor.tel["prefill_compiles"],
+                decode_compiles=self.executor.tel["decode_compiles"],
+            )
         return stats
 
     def _step_async(self) -> dict:
@@ -464,28 +463,27 @@ class Engine:
         discarded (a preempted request regenerates them after resume),
         and EDF drops touch only queued requests."""
         tr = self._tracer
-        tr.begin_step()
-        with tr.phase("schedule"):
-            decision = self.scheduler.schedule(self.executor.slots)
-        inflight = self.executor.dispatch(decision)
-        inflight.dispatched_at = self.clock()
-        self._route_dropped(decision.dropped, inflight.dispatched_at)
-        prev, self._inflight = self._inflight, inflight
-        stats = {"prefilled": 0, "decoded": 0}
-        if prev is not None:
-            out = self.executor.collect(prev)
-            ts = (
-                prev.dispatched_at
-                if prev.dispatched_at is not None
-                else self.clock()
+        with tr.step():
+            with tr.phase("schedule"):
+                decision = self.scheduler.schedule(self.executor.slots)
+            inflight = self.executor.dispatch(decision)
+            inflight.dispatched_at = self.clock()
+            self._route_dropped(decision.dropped, inflight.dispatched_at)
+            prev, self._inflight = self._inflight, inflight
+            stats = {"prefilled": 0, "decoded": 0}
+            if prev is not None:
+                out = self.executor.collect(prev)
+                ts = (
+                    prev.dispatched_at
+                    if prev.dispatched_at is not None
+                    else self.clock()
+                )
+                self._route_output(out, ts)
+                stats = out.stats
+            stats.update(
+                prefill_compiles=self.executor.tel["prefill_compiles"],
+                decode_compiles=self.executor.tel["decode_compiles"],
             )
-            self._route_output(out, ts)
-            stats = out.stats
-        stats.update(
-            prefill_compiles=self.executor.tel["prefill_compiles"],
-            decode_compiles=self.executor.tel["decode_compiles"],
-        )
-        tr.end_step()
         return stats
 
     def stream(self, handle: RequestHandle | int) -> Iterator[TokenEvent]:

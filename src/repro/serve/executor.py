@@ -121,6 +121,21 @@ class InflightStep:
         )
 
 
+def live_kv_positions(emit_t, pos_f, act_f, n_forced) -> int:
+    """KV positions of live context a decode scan's attention read: over
+    each scan step and each slot active in it, the slot's context length
+    then (its position + 1).  From what the scan returns: a slot's
+    active steps are a prefix of the scan, its teacher-forced steps
+    (which replay positions below the length cap, so none is cut
+    short) then its emitting ones; a slot still active at the end ran
+    every step, any other ran ``n_forced + emitted`` steps if it emitted
+    and none if it did not, the last of them at ``pos_f - 1``."""
+    steps = emit_t.shape[0]
+    emitted = emit_t.sum(axis=0, dtype=np.int64)
+    k = np.where(act_f, steps, np.where(emitted > 0, n_forced + emitted, 0))
+    return int((k * pos_f.astype(np.int64) - k * (k - 1) // 2).sum())
+
+
 class DraftWorker:
     """The draft side of speculative decoding: a (small) model with its
     own dense float KV cache that greedily proposes ``spec_k`` tokens per
@@ -460,6 +475,15 @@ class ModelExecutor:
             "spec_dispatches": 0,
             "spec_time_s": 0.0,
             "steps": 0,
+            # the work the programs are handed against the part of it
+            # that is live: KV positions the decode attention reads and
+            # those inside each active slot's context; prompt positions
+            # dispatched to prefill / extend and those whose KV the
+            # dispatch writes (no padding, no shared prefix pages)
+            "decode_kv_positions_read": 0,
+            "decode_kv_positions_live": 0,
+            "prompt_positions_dispatched": 0,
+            "prompt_positions_written": 0,
         }
 
         # Speculative decoding: a draft model proposes spec_tokens greedy
@@ -739,12 +763,15 @@ class ModelExecutor:
         tel, tr = self.tel, self.tracer
         decision = inflight.decision
         tr.collect_begin(inflight.t_dispatch)
-        with tr.phase(tr.collect_phase):
+        with tr.phase("collect", "decode"):
             toks_t, emit_t, tok_f, pos_f, act_f, rem_f = (
                 np.asarray(x) for x in inflight.dev
             )
         tel["decode_time_s"] += time.perf_counter() - inflight.t0
-        with tr.phase("sample"):
+        tel["decode_kv_positions_live"] += live_kv_positions(
+            emit_t, pos_f, act_f, inflight.n_forced
+        )
+        with tr.phase("sample", "decode"):
             for idx in inflight.decode_set:
                 slot = self.slots[idx]
                 req = inflight.snapshot.get(idx)
@@ -863,7 +890,7 @@ class ModelExecutor:
         rows activate with their teacher-forced tail instead."""
         sc, tel, tr = self.serve_cfg, self.tel, self.tracer
         nb = sc.max_batch
-        with tr.phase("host_prep"):
+        with tr.phase("host_prep", "prefill"):
             toks = np.zeros((nb, bucket), np.int32)
             lengths = np.zeros((nb,), np.int32)
             slots_arr = np.full((nb,), nb, np.int32)
@@ -892,18 +919,22 @@ class ModelExecutor:
             self._prefill_fn[bucket] = fn
             tel["prefill_compiles"] += 1
         t0 = time.perf_counter()
-        with tr.phase("dispatch"):
+        with tr.phase("dispatch", "prefill"):
             last, self.caches = fn(
                 self.params, jnp.asarray(toks), jnp.asarray(lengths),
                 self.caches, jnp.asarray(slots_arr), jnp.asarray(shared_arr),
             )
-        with tr.phase("device"):
+        with tr.phase("device", "prefill"):
             tr.fence((last, self.caches))
         tel["prefill_dispatches"] += 1
+        tel["prompt_positions_dispatched"] += toks.size
+        tel["prompt_positions_written"] += int(np.maximum(
+            lengths - shared_arr * self.cache_mgr.page_size, 0
+        ).sum())
         # one vectorized sample + one device->host transfer for the group;
         # the admission carries the scheduler-stamped per-request knobs
         self.key, sub = jax.random.split(self.key)
-        with tr.phase("sample"):
+        with tr.phase("sample", "prefill"):
             knobs = [adm.sampling for adm in group]
             first_tokens = np.asarray(
                 sample_tokens(
@@ -955,7 +986,7 @@ class ModelExecutor:
             return
         sc, tel, tr = self.serve_cfg, self.tel, self.tracer
         nb, w = sc.max_batch, self.extend_width
-        with tr.phase("host_prep"):
+        with tr.phase("host_prep", "extend"):
             toks = np.zeros((nb, w), np.int32)
             lens = np.zeros((nb,), np.int32)
             starts = np.zeros((nb,), np.int32)
@@ -976,16 +1007,18 @@ class ModelExecutor:
         if tel["extend_compiles"] == 0:
             tel["extend_compiles"] = 1  # one program, fixed shapes
         t0 = time.perf_counter()
-        with tr.phase("dispatch"):
+        with tr.phase("dispatch", "extend"):
             logits, self.caches = self._extend_fn(
                 self.params, jnp.asarray(toks), jnp.asarray(lens),
                 jnp.asarray(starts), self.caches,
             )
-        with tr.phase("device"):
+        with tr.phase("device", "extend"):
             tr.fence((logits, self.caches))
         tel["extend_dispatches"] += 1
+        tel["prompt_positions_dispatched"] += toks.size
+        tel["prompt_positions_written"] += int(lens.sum())
         self.key, sub = jax.random.split(self.key)
-        with tr.phase("sample"):
+        with tr.phase("sample", "extend"):
             # each row's true logits live at its window's last valid
             # position (selected eagerly — the program returns the full
             # window so speculative verification can reuse it)
@@ -1092,7 +1125,7 @@ class ModelExecutor:
         if not cand:
             return set()
         t0 = time.perf_counter()
-        with tr.phase("host_prep"):
+        with tr.phase("host_prep", "verify"):
             # resync draft cache rows whose (pos, carry) drifted from the
             # target's — any admission/extend/preempt/release turnover
             # invalidated them via _host_dirty
@@ -1127,13 +1160,13 @@ class ModelExecutor:
                 d_tok[i] = self.slots[i].last_token
                 d_pos[i] = self.slots[i].pos
                 d_act[i] = True
-        with tr.phase("dispatch"):
+        with tr.phase("dispatch", "verify"):
             toks_t, self.draft.caches = self.draft._propose_fn(
                 self.draft.params, jnp.asarray(d_tok), jnp.asarray(d_pos),
                 jnp.asarray(d_act), self.draft.caches,
             )
         props = np.asarray(toks_t)  # (k, nb)
-        with tr.phase("host_prep"):
+        with tr.phase("host_prep", "verify"):
             # verify: ONE extend dispatch over [carry, d1..d_{k-1}]
             vt = np.zeros((nb, self.extend_width), np.int32)
             vl = np.zeros((nb,), np.int32)
@@ -1148,16 +1181,16 @@ class ModelExecutor:
             self.caches = self.cache_mgr.flush_swaps(self.caches)
             self.caches = self.cache_mgr.flush_copies(self.caches)
             self.caches = self.cache_mgr.write_table(self.caches)
-        with tr.phase("dispatch"):
+        with tr.phase("dispatch", "verify"):
             logits, self.caches = self._extend_fn(
                 self.params, jnp.asarray(vt), jnp.asarray(vl),
                 jnp.asarray(vs), self.caches,
             )
-        with tr.phase("device"):
+        with tr.phase("device", "verify"):
             tr.fence((logits, self.caches))
         tel["spec_dispatches"] += 1
         self.key, sub = jax.random.split(self.key)
-        with tr.phase("sample"):
+        with tr.phase("sample", "verify"):
             knobs = [
                 encode_sampling(
                     self.slots[i].request if i in cand else None,
@@ -1254,7 +1287,7 @@ class ModelExecutor:
             return InflightStep(out=out, decision=decision)
         nb = sc.max_batch
         use_carry = self.async_loop and self._carry is not None
-        with tr.phase("host_prep"):
+        with tr.phase("host_prep", "decode"):
             forced = np.zeros((sc.decode_steps, nb), np.int32)
             n_forced = np.zeros((nb,), np.int32)
             for idx in sorted(decode_set):
@@ -1381,8 +1414,12 @@ class ModelExecutor:
         self.key, sub = jax.random.split(self.key)
         if tel["decode_compiles"] == 0:
             tel["decode_compiles"] = 1  # one program, fixed shapes
+        tel["decode_kv_positions_read"] += (
+            forced.shape[0] * tokens.shape[0]
+            * kv_cache.decode_read_len(self.caches)
+        )
         t0 = time.perf_counter()
-        with tr.phase("dispatch"):
+        with tr.phase("dispatch", "decode"):
             toks_t, emit_t, tok_f, pos_f, act_f, rem_f, self.caches = (
                 self._decode_fn(
                     self.params, tok_in, pos_in,
@@ -1393,7 +1430,7 @@ class ModelExecutor:
                     self.caches, sub,
                 )
             )
-        with tr.phase("device"):
+        with tr.phase("device", "decode"):
             tr.fence((toks_t, emit_t, tok_f, pos_f, act_f, self.caches))
         if self.async_loop:
             # every batch row's scan output reflects the merged (device

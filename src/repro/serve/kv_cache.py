@@ -509,6 +509,22 @@ def paged_decode_view(cache: dict) -> dict[str, jax.Array]:
     return out
 
 
+def decode_read_len(caches: PyTree) -> int:
+    """KV positions per slot that one decode step's attention reads from
+    ``caches`` (the stacked model caches a decode program is called
+    with): the page-table view (pages per slot x page size) on the paged
+    layout, the slab on the dense one (the rolling buffer for a sliding
+    window), 0 where no layer keeps attention state (SSM)."""
+    for group in (caches.get("layers"), caches.get("shared")):
+        for name in ("k", "latent"):
+            if group and name in group:
+                n = group[name].shape[-2]  # page size, or the slab length
+                if "page_table" in group:
+                    n *= group["page_table"].shape[-1]
+                return int(n)
+    return 0
+
+
 # ---------------------------------------------------------------------------
 # Traced helpers: prefill masking + layout-specific slot insertion
 # ---------------------------------------------------------------------------

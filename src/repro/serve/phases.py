@@ -19,9 +19,10 @@ pushes the finished record into a bounded ring buffer, and summarizes
 p50/p95/p99 on demand.  Isolating ``device`` requires *fencing* every
 dispatch (``jax.block_until_ready``), which serializes host and device
 work — so tracing is **off by default** (``ServeConfig.trace_phases``)
-and the off path is :data:`NULL_TRACER`, whose methods are no-ops and
-which never fences: an untraced engine runs the exact code it ran
-before, test-enforced to cost no measurable throughput.
+and the off path is :data:`NULL_TRACER`, which keeps no records and
+never fences: an untraced engine does no extra device work, and its
+only extra host work is the profiler spans below, test-enforced to cost
+no measurable throughput.
 
 The fenced tracer *destroys the pipeline it measures*: under the
 PR-8 async engine loop (``ServeConfig.async_loop``) a fence between
@@ -48,9 +49,19 @@ seconds — even when the engine itself runs on a virtual clock
 (:class:`~repro.serve.workloads.StepClock`): phase timings are physical
 measurements, arrival/deadline bookkeeping is simulation time.
 
-This module stays importable without jax (the single
-``block_until_ready`` call imports lazily), so host-side tooling can
-consume recorded phase data anywhere the scheduler runs.
+Every tracer, :data:`NULL_TRACER` included, also writes each phase as a
+``jax.profiler.TraceAnnotation`` span on the profiler's clock, the
+clock the device trace shares: ``serve.step`` around a whole engine
+step, and inside it ``serve.schedule``, ``serve.host_prep``,
+``serve.dispatch``, ``serve.device`` (fenced tracer only: elsewhere
+nothing is waited for), ``serve.sample`` and ``serve.collect`` (the
+decode scan's blocking device->host transfer, whatever ring key the
+tracer files it under).  A span inside a step carries the engine
+program it serves as the argument ``program`` (``prefill``,
+``extend``, ``decode`` or ``verify``).  A span records nothing unless
+a profiler session is active (``jax.profiler.start_trace`` or
+``trace``); without one it costs about a microsecond.  So one set of
+phases feeds two sinks: any profiler trace, and the opt-in ring.
 """
 
 from __future__ import annotations
@@ -58,8 +69,14 @@ from __future__ import annotations
 import collections
 import time
 
+import jax
+from jax.profiler import TraceAnnotation
+
 #: phase names in within-step order (``wall`` is the whole step)
 PHASES = ("schedule", "host_prep", "dispatch", "device", "sample")
+#: profiler span name of each phase, of the decode scan's transfer and
+#: of the whole step
+SPANS = {name: "serve." + name for name in PHASES + ("collect", "step")}
 
 
 def _percentile(xs: list[float], q: float) -> float:
@@ -69,6 +86,14 @@ def _percentile(xs: list[float], q: float) -> float:
         return 0.0
     idx = min(len(xs) - 1, max(0, int(round(q / 100.0 * (len(xs) - 1)))))
     return xs[idx]
+
+
+def _span(name: str, program: str | None = None):
+    """The profiler span of phase ``name`` (it records nothing when no
+    profiler session is active)."""
+    if program is None:
+        return TraceAnnotation(SPANS[name])
+    return TraceAnnotation(SPANS[name], program=program)
 
 
 class _NullCtx:
@@ -84,16 +109,25 @@ class _NullCtx:
         return False
 
 
+_NULL_CTX = _NullCtx()
+
+
 class NullTracer:
-    """The off switch: every hook is a no-op and :meth:`fence` never
-    touches the device, so an untraced engine's hot loop is unchanged."""
+    """The off switch for the ring: every hook is a no-op, nothing is
+    recorded and :meth:`fence` never touches the device, so an untraced
+    engine's hot loop runs no extra device work.  Its phases are still
+    profiler spans, which record only under an active profiler
+    session."""
 
     enabled = False
-    _ctx = _NullCtx()
-    #: record key the executor wraps collect's blocking transfer in
-    #: ("sample" keeps the fenced/untraced record schema; the overlap
-    #: tracer renames it "collect" — the pipeline-bubble measurement)
+    #: record key the executor's ``collect`` phase (the blocking
+    #: transfer) is filed under ("sample" keeps the fenced/untraced
+    #: record schema; the overlap tracer files it as "collect" — the
+    #: pipeline-bubble measurement).  Its span is ``serve.collect``.
     collect_phase = "sample"
+    #: phases that write no span: without a fence ``device`` waits for
+    #: nothing
+    unspanned = frozenset({"device"})
 
     def begin_step(self) -> None:
         pass
@@ -101,8 +135,17 @@ class NullTracer:
     def end_step(self) -> None:
         pass
 
-    def phase(self, name: str) -> _NullCtx:
-        return self._ctx
+    def step(self):
+        """Context manager around one engine step: the ``serve.step``
+        span."""
+        return _span("step")
+
+    def phase(self, name: str, program: str | None = None):
+        """Context manager around one phase: its span, tagged with the
+        engine ``program`` it serves."""
+        if name in self.unspanned:
+            return _NULL_CTX
+        return _span(name, program)
 
     def fence(self, value):
         return value
@@ -130,15 +173,19 @@ NULL_TRACER = NullTracer()
 class _PhaseCtx:
     """Context manager accumulating elapsed seconds into the tracer's
     current step record under ``name`` (re-entrant per step: repeated
-    phases — one per dispatch — sum)."""
+    phases — one per dispatch — sum), inside the phase's span."""
 
-    __slots__ = ("tracer", "name", "t0")
+    __slots__ = ("tracer", "name", "span", "t0")
 
-    def __init__(self, tracer: PhaseTracer, name: str):
+    def __init__(self, tracer: PhaseTracer, name: str, program: str | None):
         self.tracer = tracer
-        self.name = name
+        self.name = tracer.collect_phase if name == "collect" else name
+        self.span = (
+            _NULL_CTX if name in tracer.unspanned else _span(name, program)
+        )
 
     def __enter__(self):
+        self.span.__enter__()
         self.t0 = time.perf_counter()
         return self
 
@@ -148,6 +195,29 @@ class _PhaseCtx:
             cur[self.name] = (
                 cur.get(self.name, 0.0) + time.perf_counter() - self.t0
             )
+        self.span.__exit__(*exc)
+        return False
+
+
+class _StepCtx:
+    """Context manager around one engine step: the ``serve.step`` span
+    and the tracer's step record (kept if the step returned)."""
+
+    __slots__ = ("tracer", "span")
+
+    def __init__(self, tracer: PhaseTracer):
+        self.tracer = tracer
+        self.span = _span("step")
+
+    def __enter__(self):
+        self.span.__enter__()
+        self.tracer.begin_step()
+        return self
+
+    def __exit__(self, exc_type, *exc):
+        if exc_type is None:
+            self.tracer.end_step()
+        self.span.__exit__(exc_type, *exc)
         return False
 
 
@@ -156,14 +226,13 @@ class PhaseTracer:
 
     Usage (the engine/executor wiring)::
 
-        tracer.begin_step()
-        with tracer.phase("schedule"):
-            decision = scheduler.schedule(slots)
-        with tracer.phase("dispatch"):
-            out = jitted(...)            # returns once enqueued
-        with tracer.phase("device"):
-            tracer.fence(out)            # block_until_ready
-        tracer.end_step()
+        with tracer.step():              # begin_step ... end_step
+            with tracer.phase("schedule"):
+                decision = scheduler.schedule(slots)
+            with tracer.phase("dispatch", "decode"):
+                out = jitted(...)        # returns once enqueued
+            with tracer.phase("device", "decode"):
+                tracer.fence(out)        # block_until_ready
 
     ``fence`` is the only device-touching call and exists so the *same*
     executor source runs fenced and unfenced: under :data:`NULL_TRACER`
@@ -175,6 +244,8 @@ class PhaseTracer:
     _names = PHASES
     #: see NullTracer.collect_phase
     collect_phase = "sample"
+    #: see NullTracer.unspanned: the fenced tracer spans every phase
+    unspanned = frozenset()
 
     def __init__(self, ring: int = 512):
         if ring < 1:
@@ -198,15 +269,16 @@ class PhaseTracer:
         self._ring.append(self._cur)
         self._cur = None
 
-    def phase(self, name: str) -> _PhaseCtx:
-        return _PhaseCtx(self, name)
+    def step(self) -> _StepCtx:
+        return _StepCtx(self)
+
+    def phase(self, name: str, program: str | None = None) -> _PhaseCtx:
+        return _PhaseCtx(self, name, program)
 
     def fence(self, value):
         """Wait for every array in ``value`` (pytree) to be ready on
         device.  Call inside a ``phase("device")`` block, right after the
         dispatch returned, to split launch time from device time."""
-        import jax  # lazy: keep the module importable host-side
-
         self.fences += 1
         return jax.block_until_ready(value)
 
@@ -280,6 +352,7 @@ class OverlapTracer(PhaseTracer):
 
     _names = PHASES + ("collect", "overlap")
     collect_phase = "collect"
+    unspanned = NullTracer.unspanned
 
     def fence(self, value):
         """Never blocks — fencing would serialize the pipeline this

@@ -70,7 +70,12 @@ class Request:
     #: queue-wait clock; a preemption restamps it at requeue so the next
     #: admission's wait measures time-to-resume, not time-since-submit
     submitted_at: float = 0.0
+    #: the latest admission: after a preemption it is re-stamped, so
+    #: ``queue_wait_s`` measures time-to-resume
     admitted_at: float = 0.0
+    #: the first admission, stamped once; a preemption never touches it
+    #: (None while the request has never been admitted)
+    first_admitted_at: float | None = None
     #: absolute completion deadline on the engine clock (None = no SLO);
     #: the EDF policy (serve/slo.py) orders the queue by it and may drop
     #: a queued request once it passes (finish_reason="deadline")
@@ -523,6 +528,23 @@ class FifoScheduler:
         self.queue.insert(1, req)
         return True
 
+    def _stamp_admission(self, req: Request) -> None:
+        """Stamp a request popped for admission.  A preemption-resume
+        adds its re-wait to the total but the prompt counts once, and
+        only the first admission sets ``first_admitted_at``."""
+        now = self.clock()
+        if req.first_admitted_at is None:
+            self.stats["prompts_admitted"] += 1
+            req.first_admitted_at = now
+        req.admitted_at = now
+        self.stats["queue_wait_s_total"] += req.queue_wait_s
+        # the created_at-anchored companion key: for a preemption resume
+        # this spans prior residencies too, so preempted requests'
+        # time-in-system is never silently undercounted
+        self.stats["queue_wait_created_s_total"] += max(
+            0.0, req.admitted_at - req.created_at
+        )
+
     def _pick_victim(self, victims: list[int], slots: list[Slot]) -> int:
         """Choose which preemptable resident to evict.  FIFO preempts
         the youngest (largest admit_seq) so the head-of-line request
@@ -581,13 +603,7 @@ class FifoScheduler:
             # takes the fallback path, which is still correct
             return "retry" if self._try_preempt(slots, free, decision) else "wait"
         req = self.queue.pop(0)
-        if req.admitted_at == 0.0:
-            self.stats["prompts_admitted"] += 1
-        req.admitted_at = self.clock()
-        self.stats["queue_wait_s_total"] += req.queue_wait_s
-        self.stats["queue_wait_created_s_total"] += max(
-            0.0, req.admitted_at - req.created_at
-        )
+        self._stamp_admission(req)
         idx = free.pop(0)
         self._admit_seq += 1
         shared = self.cache.fork(idx, pidx, upto, reserve_len)
@@ -704,18 +720,8 @@ class FifoScheduler:
                 break
             req = self.queue.pop(0)
             # queue wait ends at pop: prefill execution/compile time that
-            # follows is prefill_time_s, not waiting.  A preemption-resume
-            # adds its re-wait to the total but the prompt counts once.
-            if req.admitted_at == 0.0:
-                self.stats["prompts_admitted"] += 1
-            req.admitted_at = self.clock()
-            self.stats["queue_wait_s_total"] += req.queue_wait_s
-            # the created_at-anchored companion key: for a preemption
-            # resume this spans prior residencies too, so preempted
-            # requests' time-in-system is never silently undercounted
-            self.stats["queue_wait_created_s_total"] += max(
-                0.0, req.admitted_at - req.created_at
-            )
+            # follows is prefill_time_s, not waiting
+            self._stamp_admission(req)
             n_admitted += 1
             idx = free.pop(0)
             self._admit_seq += 1

@@ -53,21 +53,52 @@ PROMPTS = ([5, 9, 3, 7], [11, 2, 6], [1, 2, 3, 4, 5, 6, 7, 8], [4, 4])
 # ------------------------------------------------------ tracer unit --
 
 
-def test_null_tracer_is_inert():
+def _host_spans(logdir) -> list[tuple[str, dict]]:
+    """(name, arguments) of every ``serve.*`` event on the host planes of
+    the profiler trace written under ``logdir``."""
+    from pathlib import Path
+
+    from jax.profiler import ProfileData
+
+    (path,) = Path(logdir).rglob("*.xplane.pb")
+    data = ProfileData.from_file(str(path))
+    return [(e.name, dict(e.stats)) for plane in data.planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events
+            if e.name.startswith("serve.")]
+
+
+def test_null_tracer_is_inert(tmp_path):
     tr = make_tracer(False)
     assert tr is NULL_TRACER
     assert isinstance(tr, NullTracer)
     assert not tr.enabled
-    # the fence is a pass-through that never imports jax / touches
-    # device; phase() hands back one shared no-op context manager
+    # the fence is a pass-through that never touches the device, and
+    # nothing counts fences
     sentinel = object()
     assert tr.fence(sentinel) is sentinel
-    assert tr.phase("device") is tr.phase("sample")
-    with tr.phase("anything"):
-        pass
+    assert not hasattr(tr, "fences")
+    # phases outside a profiler session: no records, no spans kept
+    with tr.step():
+        with tr.phase("sample", "decode"):
+            pass
     tr.begin_step(), tr.end_step()
     assert tr.records() == []
     assert tr.summary() == {}
+    # the same phases inside a session are spans; "device" waits for
+    # nothing without a fence and writes none
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.step():
+            with tr.phase("host_prep", "decode"):
+                pass
+            with tr.phase("device", "decode"):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    assert sorted(_host_spans(tmp_path)) == [
+        ("serve.host_prep", {"program": "decode"}), ("serve.step", {})]
+    assert tr.records() == []
 
 
 def test_phase_tracer_accumulates_and_bounds_ring():
@@ -309,3 +340,126 @@ def test_wait_keys_equal_without_preemption(cfg, params):
     assert tel["queue_wait_created_s_total"] == pytest.approx(
         tel["queue_wait_s_total"]
     )
+
+
+def test_first_admission_stamp_survives_preemption(cfg, params):
+    """``first_admitted_at`` is stamped once, at the first admission; a
+    preemption re-stamps ``admitted_at`` (time-to-resume) and leaves
+    it alone."""
+    clock = StepClock()
+    eng = _engine(
+        cfg, params, clock=clock, kv_layout="paged", kv_page_size=8,
+        kv_pages=5, max_seq_len=32, kv_prefix_cache=True,
+        kv_preemption=True,
+    )
+    rng = np.random.default_rng(0)
+    handles = [
+        eng.submit(list(rng.integers(0, cfg.vocab_size, 6)),
+                   max_new_tokens=20)
+        for _ in range(4)
+    ]
+    assert all(eng.request(h).first_admitted_at is None for h in handles)
+    first: dict[int, float] = {}
+    while eng.has_work:
+        clock.advance(0.01)
+        eng.step()
+        for h in handles:
+            req = eng.request(h)
+            if req.first_admitted_at is not None:
+                assert first.setdefault(h.uid, req.first_admitted_at) == (
+                    req.first_admitted_at)
+    reqs = [eng.result(h) for h in handles]
+    assert [r.first_admitted_at for r in reqs] == [first[h.uid] for h in handles]
+    preempted = [r for r in reqs if r.preemptions > 0]
+    assert preempted  # the pool genuinely thrashed
+    for r in preempted:
+        assert r.admitted_at > r.first_admitted_at >= r.created_at
+    assert eng.telemetry["prompts_admitted"] == len(handles)
+
+
+# ------------------------------------------------------ waste counters --
+
+
+def _count_calls(eng, name):
+    """Wrap one of the executor's jitted programs to count its calls."""
+    calls = []
+    fn = getattr(eng.executor, name)
+
+    def counted(*args):
+        calls.append(args)
+        return fn(*args)
+
+    setattr(eng.executor, name, counted)
+    return calls
+
+
+def _live(first_pos: int, req) -> int:
+    """By hand: a request's decode steps process positions ``first_pos``
+    (its first position not written by prefill or extend) up to the
+    one before its last token; each reads its position + 1 live KV."""
+    last = len(req.prompt) + len(req.generated) - 2
+    return sum(p + 1 for p in range(first_pos, last + 1))
+
+
+@pytest.mark.parametrize("async_loop", [False, True])
+@pytest.mark.parametrize("layout", ["dense", "paged"])
+def test_waste_counters_equal_hand_counts(cfg, params, layout, async_loop):
+    eng = _engine(cfg, params, kv_layout=layout, kv_page_size=8,
+                  async_loop=async_loop)
+    decodes = _count_calls(eng, "_decode_fn")
+    handles = [eng.submit(list(p), max_new_tokens=5 + i)
+               for i, p in enumerate(PROMPTS)]
+    eng.generate()
+    tel, sc = eng.telemetry, eng.serve_cfg
+    reqs = [eng.result(h) for h in handles]
+    assert decodes and all(len(r.generated) == 5 + i for i, r in enumerate(reqs))
+    assert tel["decode_kv_positions_read"] == (
+        len(decodes) * sc.decode_steps * sc.max_batch * sc.max_seq_len)
+    assert tel["decode_kv_positions_live"] == sum(
+        _live(len(r.prompt), r) for r in reqs)
+    # every prompt fits the 8-token bucket
+    assert tel["prompt_positions_dispatched"] == (
+        tel["prefill_dispatches"] * sc.max_batch * 8)
+    assert tel["prompt_positions_written"] == sum(len(p) for p in PROMPTS)
+
+
+@pytest.mark.parametrize("async_loop", [False, True])
+def test_waste_counters_leave_out_shared_prefix_pages(cfg, params, async_loop):
+    """A prefix hit writes no prompt KV through prefill: its tail past the
+    two shared pages rides the decode scan teacher-forced, and those
+    steps read live KV like any other."""
+    eng = _engine(cfg, params, kv_layout="paged", kv_page_size=8,
+                  kv_prefix_cache=True, async_loop=async_loop)
+    rng = np.random.default_rng(3)
+    shared = list(rng.integers(1, cfg.vocab_size, 16))
+    a = eng.submit(shared + [7, 8, 9, 10], max_new_tokens=4)
+    eng.generate()
+    b = eng.submit(shared + [3, 1, 4, 1, 5], max_new_tokens=5)
+    eng.generate()
+    tel = eng.telemetry
+    assert tel["prefill_tokens_saved"] == 16
+    ra, rb = eng.result(a), eng.result(b)
+    assert tel["prompt_positions_written"] == 20
+    assert tel["prompt_positions_dispatched"] == 2 * 32  # one 32 bucket
+    assert tel["decode_kv_positions_live"] == _live(20, ra) + _live(16, rb)
+
+
+def test_waste_counters_count_extend_windows():
+    """Chunked prefill on a datapath that replays through the extend
+    program: a 20-token prompt is an 8-token chunk (one 2 x 8 prefill)
+    and a 12-token tail (two 2 x 8 extend windows)."""
+    from repro.core import precision as P
+
+    kv8 = P.PrecisionPolicy(
+        "kv8", (P.Rule("kv_cache", P.int8(per_channel=False)),))
+    cfg = configs.get_config("granite-8b", reduced=True)
+    eng = _engine(cfg, lm.init_params(cfg, KEY), policy=kv8,
+                  prefill_chunk=8)
+    prompt = list(np.random.default_rng(0).integers(1, cfg.vocab_size, 20))
+    h = eng.submit(prompt, max_new_tokens=5)
+    eng.generate()
+    tel = eng.telemetry
+    assert (tel["prefill_dispatches"], tel["extend_dispatches"]) == (1, 2)
+    assert tel["prompt_positions_dispatched"] == 2 * 8 + 2 * (2 * 8)
+    assert tel["prompt_positions_written"] == 20
+    assert tel["decode_kv_positions_live"] == _live(20, eng.result(h))
