@@ -146,13 +146,17 @@ def gqa_apply(
     )
     quantized = cache is not None and "k_scale" in cache
 
-    if quantized:
-        k_store, k_sc = _kv_quantize(k)
-        v_store, v_sc = _kv_quantize(v)
-    elif cache is not None:
-        k_store = k.astype(cache["k"].dtype)
-        v_store = v.astype(cache["v"].dtype)
-        k_sc = v_sc = None
+    if cache is not None:
+        if quantized:
+            k_store, k_sc = _kv_quantize(k)
+            v_store, v_sc = _kv_quantize(v)
+        else:
+            k_store = k.astype(cache["k"].dtype)
+            v_store = v.astype(cache["v"].dtype)
+        # what this call writes, per slot along the sequence axis (2)
+        upd = {"k": k_store, "v": v_store}
+        if quantized:
+            upd["k_scale"], upd["v_scale"] = k_sc, v_sc
 
     if mode in ("train",) or cache is None:
         out = fused_mha(
@@ -205,21 +209,7 @@ def gqa_apply(
                 new_cache["k_scale"] = scatter3(cache["k_scale"], tail3(k_sc))
                 new_cache["v_scale"] = scatter3(cache["v_scale"], tail3(v_sc))
         else:
-            new_cache = {
-                "k": jax.lax.dynamic_update_slice(
-                    cache["k"], k_store, (0, 0, 0, 0)
-                ),
-                "v": jax.lax.dynamic_update_slice(
-                    cache["v"], v_store, (0, 0, 0, 0)
-                ),
-            }
-            if quantized:
-                new_cache["k_scale"] = jax.lax.dynamic_update_slice(
-                    cache["k_scale"], k_sc, (0, 0, 0)
-                )
-                new_cache["v_scale"] = jax.lax.dynamic_update_slice(
-                    cache["v_scale"], v_sc, (0, 0, 0)
-                )
+            new_cache = kv_cache_lib.dense_prefill_write(cache, upd)
         if quantized:
             # attend the cache's own representation (int8 roundtrip):
             # prefill scores the exact values decode and cache-extend
@@ -252,15 +242,8 @@ def gqa_apply(
                 "cache-extend requires a position-addressed cache; "
                 "rolling sliding-window buffers prefill exact-length"
             )
-        upd = {"k": k_store, "v": v_store}
-        if quantized:
-            upd["k_scale"], upd["v_scale"] = k_sc, v_sc
-        if kv_cache_lib.is_paged(cache):
-            new_cache = kv_cache_lib.paged_window_write(cache, upd, positions)
-            view = kv_cache_lib.paged_decode_view(new_cache)
-        else:
-            new_cache = kv_cache_lib.dense_window_write(cache, upd, positions)
-            view = new_cache
+        new_cache = kv_cache_lib.window_write(cache, upd, positions)
+        view = kv_cache_lib.decode_view(new_cache, cfg.n_kv_heads)
         kv_pos = jnp.arange(view["k"].shape[2])
         mask = kv_pos[None, None, :] <= positions[:, :, None]  # (B, W, L)
         if window is not None:
@@ -273,36 +256,20 @@ def gqa_apply(
         )
     else:  # decode: s == 1, attend over cache; positions is (B,) per-seq
         pos = positions  # (B,)
+        tok = {name: u[:, :, 0] for name, u in upd.items()}
         if kv_cache_lib.is_paged(cache):
             # layout-provided scatter (one token into its physical page)
             # and gather (pages -> contiguous logical view): the math
             # below is then bit-identical to the dense slab path.
-            upd = {"k": k_store[:, :, 0], "v": v_store[:, :, 0]}
-            if quantized:
-                upd["k_scale"] = k_sc[:, :, 0]
-                upd["v_scale"] = v_sc[:, :, 0]
-            new_cache = kv_cache_lib.paged_decode_write(cache, upd, pos)
-            view = kv_cache_lib.paged_decode_view(new_cache)
+            new_cache = kv_cache_lib.paged_decode_write(cache, tok, pos)
         else:
-            bi = jnp.arange(b)[:, None]
-            hi = jnp.arange(cfg.n_kv_heads)[None, :]
             slot = pos % window if rolling else pos  # (B,)
-            new_cache = {
-                "k": cache["k"].at[bi, hi, slot[:, None]].set(k_store[:, :, 0]),
-                "v": cache["v"].at[bi, hi, slot[:, None]].set(v_store[:, :, 0]),
-            }
-            if quantized:
-                new_cache["k_scale"] = cache["k_scale"].at[
-                    bi, hi, slot[:, None]
-                ].set(k_sc[:, :, 0])
-                new_cache["v_scale"] = cache["v_scale"].at[
-                    bi, hi, slot[:, None]
-                ].set(v_sc[:, :, 0])
+            new_cache = kv_cache_lib.dense_decode_write(cache, tok, slot)
             if rolling:
                 new_cache["slot_pos"] = cache["slot_pos"].at[
                     jnp.arange(b), slot
                 ].set(pos)
-            view = new_cache
+        view = kv_cache_lib.decode_view(new_cache, cfg.n_kv_heads)
         if rolling:
             slot_pos = new_cache["slot_pos"]
             valid = (
@@ -492,6 +459,10 @@ def mla_apply(
             ).astype(jnp.int8)
         else:
             l_store, l_scale = latent.astype(cache_dtype), None
+        # what this call writes, per slot along the sequence axis (1)
+        upd = {"latent": l_store}
+        if quantized:
+            upd["latent_scale"] = l_scale.astype(jnp.float32)
         if mode == "prefill":
             if kv_cache_lib.is_paged(cache):
                 raise ValueError(
@@ -499,46 +470,21 @@ def mla_apply(
                     "paged storage via serve.kv_cache.CacheManager"
                     ".insert_prefill"
                 )
-            new_latent = jax.lax.dynamic_update_slice(
-                cache["latent"], l_store, (0, 0, 0)
-            )
-            new_cache = {"latent": new_latent}
-            if quantized:
-                new_cache["latent_scale"] = jax.lax.dynamic_update_slice(
-                    cache["latent_scale"], l_scale.astype(jnp.float32), (0, 0)
-                )
+            new_cache = kv_cache_lib.dense_prefill_write(cache, upd)
         elif mode == "extend":  # window scatter at (B, W) positions
-            upd = {"latent": l_store}
-            if quantized:
-                upd["latent_scale"] = l_scale.astype(jnp.float32)
+            new_cache = kv_cache_lib.window_write(cache, upd, positions)
+        else:  # decode: positions is (B,)
+            tok = {name: u[:, 0] for name, u in upd.items()}
             write = (
-                kv_cache_lib.paged_window_write
+                kv_cache_lib.paged_decode_write
                 if kv_cache_lib.is_paged(cache)
-                else kv_cache_lib.dense_window_write
+                else kv_cache_lib.dense_decode_write
             )
-            new_cache = write(cache, upd, positions)
-        elif kv_cache_lib.is_paged(cache):  # paged decode: page scatter
-            upd = {"latent": l_store[:, 0]}
-            if quantized:
-                upd["latent_scale"] = l_scale[:, 0].astype(jnp.float32)
-            new_cache = kv_cache_lib.paged_decode_write(cache, upd, positions)
-        else:  # dense decode: positions is (B,)
-            new_latent = cache["latent"].at[jnp.arange(b), positions].set(
-                l_store[:, 0]
-            )
-            new_cache = {"latent": new_latent}
-            if quantized:
-                new_cache["latent_scale"] = cache["latent_scale"].at[
-                    jnp.arange(b), positions
-                ].set(l_scale[:, 0].astype(jnp.float32))
+            new_cache = write(cache, tok, positions)
 
     if mode == "decode" and cache is not None:
         pos = positions  # (B,)
-        view = (
-            kv_cache_lib.paged_decode_view(new_cache)
-            if kv_cache_lib.is_paged(new_cache)
-            else new_cache
-        )
+        view = kv_cache_lib.decode_view(new_cache)
         lat = view["latent"].astype(jnp.float32)  # (b, L, r+rope_d)
         if quantized:
             lat = lat * view["latent_scale"][..., None]
@@ -582,11 +528,7 @@ def mla_apply(
         # latent exactly as the whole-prompt prefill does — so window
         # activations and cache entries are bitwise what that prefill
         # would have produced at the same positions.
-        view = (
-            kv_cache_lib.paged_decode_view(new_cache)
-            if kv_cache_lib.is_paged(new_cache)
-            else new_cache
-        )
+        view = kv_cache_lib.decode_view(new_cache)
         lat = view["latent"].astype(jnp.float32)  # (b, L, r+rope_d)
         if quantized:
             lat = lat * view["latent_scale"][..., None]

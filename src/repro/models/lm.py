@@ -185,6 +185,11 @@ def _run_blocks(
     layer_caches = caches["layers"] if caches is not None else None
     shared_cache = caches.get("shared") if caches is not None else None
     aux0 = _aux_init(cfg)
+    # Position-addressed caches ride the carry whole: each layer writes
+    # its tokens into the stacked buffers at its own index, in place.
+    # State-sized caches are sliced per layer (scan xs -> ys).
+    in_place = kv_cache_lib.rides_layer_carry(layer_caches)
+    carried = layer_caches if in_place else None
 
     plan = plan if plan is not None else precision_lib.resolve_model_plan(cfg)
     # Homogeneous plans keep the legacy single-QuantConfig trace; per-layer
@@ -197,9 +202,11 @@ def _run_blocks(
     )
 
     def body(carry, xs):
-        x, shared_c, aux = carry
+        x, shared_c, stacked, aux = carry
         bparams, lcache, idx, *rest = xs
         lquant = rest[0] if rest else uniform_quant
+        if in_place:
+            lcache = kv_cache_lib.layer_cache(stacked, idx)
         if cfg.family == "hybrid":
             is_attn = (idx % cfg.hybrid.attn_every) == 0
             app_idx = idx // cfg.hybrid.attn_every
@@ -225,7 +232,9 @@ def _run_blocks(
         )
         x = _constrain_acts(x, kernel)
         aux = {k: aux[k] + l_aux.get(k, 0.0) for k in aux}
-        return (x, shared_c, aux), new_lcache
+        if in_place:
+            stacked, new_lcache = kv_cache_lib.stacked_cache(new_lcache), None
+        return (x, shared_c, stacked, aux), new_lcache
 
     if remat == "full":
         body = jax.checkpoint(body, prevent_cse=False)
@@ -236,12 +245,18 @@ def _run_blocks(
             prevent_cse=False,
         )
 
-    xs = (params["blocks"], layer_caches, jnp.arange(cfg.n_layers))
+    xs = (
+        params["blocks"],
+        None if in_place else layer_caches,
+        jnp.arange(cfg.n_layers),
+    )
     if layer_quants is not None:
         xs = xs + (layer_quants,)
-    (x, shared_cache, aux), new_layer_caches = jax.lax.scan(
-        body, (h, shared_cache, aux0), xs
+    (x, shared_cache, carried, aux), new_layer_caches = jax.lax.scan(
+        body, (h, shared_cache, carried, aux0), xs
     )
+    if in_place:
+        new_layer_caches = carried
     new_caches = None
     if caches is not None:
         new_caches = {"layers": new_layer_caches}

@@ -441,20 +441,18 @@ class ModelExecutor:
             and self.extend_width > 0
             and not self.kernel.get("use_pallas", False)
         )
+        # Every program consumes the caches it is handed (donation), so
+        # the pool is updated in place across the program boundary; the
+        # caller rebinds ``self.caches`` to the program's output
         rep, csh = self._rep_sh, self._cache_out_sh
-        self._decode_fn = jax.jit(
-            self._decode_scan,
-            out_shardings=(rep, rep, rep, rep, rep, rep, csh),
-        ) if self.mesh is not None else jax.jit(self._decode_scan)
+        self._decode_fn = self._jit(
+            self._decode_scan, 12, (rep, rep, rep, rep, rep, rep, csh)
+        )
         self._prefill_fn: dict[int, Any] = {}  # jit cache per bucket length
-        if not self.cache_extend:
-            self._extend_fn = None
-        elif self.mesh is not None:
-            self._extend_fn = jax.jit(
-                self._extend_batch, out_shardings=(rep, csh)
-            )
-        else:
-            self._extend_fn = jax.jit(self._extend_batch)
+        self._extend_fn = (
+            self._jit(self._extend_batch, 4, (rep, csh))
+            if self.cache_extend else None
+        )
         # Step-phase tracer (serve/phases.py), assigned by the Engine when
         # ServeConfig.trace_phases is on.  The default NULL_TRACER is a
         # shared no-op whose fence() never touches the device, so the
@@ -467,6 +465,11 @@ class ModelExecutor:
             "decode_compiles": 0,
             "extend_compiles": 0,
             "extend_dispatches": 0,
+            "decode_dispatches": 0,
+            # dispatches whose KV pool input the program consumed
+            # (donated): every prefill, extend, verify and decode
+            # dispatch should count here
+            "kv_pool_donated": 0,
             "prefill_time_s": 0.0,
             "decode_time_s": 0.0,
             "extend_time_s": 0.0,
@@ -520,6 +523,21 @@ class ModelExecutor:
             self.draft = DraftWorker(
                 dcfg, dparams, sc, self.buckets, self.spec_k
             )
+
+    def _jit(self, fn, caches_arg: int, out_shardings):
+        """``jax.jit`` of an engine program that donates its ``caches``
+        argument (positional index ``caches_arg``); on a mesh its
+        outputs are pinned to ``out_shardings``."""
+        if self.mesh is None:
+            return jax.jit(fn, donate_argnums=caches_arg)
+        return jax.jit(
+            fn, out_shardings=out_shardings, donate_argnums=caches_arg
+        )
+
+    def _count_donated(self, pool: jax.Array) -> None:
+        """Count a dispatch whose KV pool input (``pool``, a leaf of the
+        caches it was handed) JAX consumed."""
+        self.tel["kv_pool_donated"] += int(pool.is_deleted())
 
     # ------------------------------------------------------------- view --
     @property
@@ -909,21 +927,19 @@ class ModelExecutor:
             self.caches = self.cache_mgr.write_table(self.caches)
         fn = self._prefill_fn.get(bucket)
         if fn is None:
-            if self.mesh is not None:
-                fn = jax.jit(
-                    self._prefill_batch,
-                    out_shardings=(self._rep_sh, self._cache_out_sh),
-                )
-            else:
-                fn = jax.jit(self._prefill_batch)
+            fn = self._jit(
+                self._prefill_batch, 3, (self._rep_sh, self._cache_out_sh)
+            )
             self._prefill_fn[bucket] = fn
             tel["prefill_compiles"] += 1
         t0 = time.perf_counter()
+        pool = kv_cache.pool_leaf(self.caches)
         with tr.phase("dispatch", "prefill"):
             last, self.caches = fn(
                 self.params, jnp.asarray(toks), jnp.asarray(lengths),
                 self.caches, jnp.asarray(slots_arr), jnp.asarray(shared_arr),
             )
+        self._count_donated(pool)
         with tr.phase("device", "prefill"):
             tr.fence((last, self.caches))
         tel["prefill_dispatches"] += 1
@@ -1007,11 +1023,13 @@ class ModelExecutor:
         if tel["extend_compiles"] == 0:
             tel["extend_compiles"] = 1  # one program, fixed shapes
         t0 = time.perf_counter()
+        pool = kv_cache.pool_leaf(self.caches)
         with tr.phase("dispatch", "extend"):
             logits, self.caches = self._extend_fn(
                 self.params, jnp.asarray(toks), jnp.asarray(lens),
                 jnp.asarray(starts), self.caches,
             )
+        self._count_donated(pool)
         with tr.phase("device", "extend"):
             tr.fence((logits, self.caches))
         tel["extend_dispatches"] += 1
@@ -1181,11 +1199,13 @@ class ModelExecutor:
             self.caches = self.cache_mgr.flush_swaps(self.caches)
             self.caches = self.cache_mgr.flush_copies(self.caches)
             self.caches = self.cache_mgr.write_table(self.caches)
+        pool = kv_cache.pool_leaf(self.caches)
         with tr.phase("dispatch", "verify"):
             logits, self.caches = self._extend_fn(
                 self.params, jnp.asarray(vt), jnp.asarray(vl),
                 jnp.asarray(vs), self.caches,
             )
+        self._count_donated(pool)
         with tr.phase("device", "verify"):
             tr.fence((logits, self.caches))
         tel["spec_dispatches"] += 1
@@ -1419,6 +1439,7 @@ class ModelExecutor:
             * kv_cache.decode_read_len(self.caches)
         )
         t0 = time.perf_counter()
+        pool = kv_cache.pool_leaf(self.caches)
         with tr.phase("dispatch", "decode"):
             toks_t, emit_t, tok_f, pos_f, act_f, rem_f, self.caches = (
                 self._decode_fn(
@@ -1430,6 +1451,8 @@ class ModelExecutor:
                     self.caches, sub,
                 )
             )
+        tel["decode_dispatches"] += 1
+        self._count_donated(pool)
         with tr.phase("device", "decode"):
             tr.fence((toks_t, emit_t, tok_f, pos_f, act_f, self.caches))
         if self.async_loop:

@@ -33,10 +33,16 @@ Two layouts share one interface:
 The attention layer does not assume a contiguous sequence axis: it asks
 ``is_paged(cache)`` and goes through :func:`paged_decode_write` /
 :func:`paged_decode_view` (gather/scatter views) when the per-layer
-cache is a page pool.  Prefill always fills a *dense* scratch cache
-(the model's natural contiguous write), and the engine's jitted prefill
-program inserts it through :meth:`CacheManager.insert_prefill`, which
-is the only layout-specific step.
+cache is a page pool.  The pool is updated in place: position-addressed
+caches ride the model's layer loop whole (:func:`rides_layer_carry`),
+each layer handed the stacked cache plus its index
+(:func:`layer_cache`), so its token writes land straight in the stacked
+buffers and its reads gather straight from them; the engine's programs
+donate the caches they are handed.  Prefill always fills a *dense*
+scratch cache (the model's natural contiguous write), and the engine's
+jitted prefill program inserts it through
+:meth:`CacheManager.insert_prefill`, which is the only layout-specific
+step.
 
 Families whose state is not position-addressed (SSM/hybrid state,
 rolling sliding-window buffers) cannot be paged; the manager silently
@@ -103,11 +109,21 @@ SEQ_AXIS_FROM_RIGHT = {
     "k_scale": 1, "v_scale": 1, "latent_scale": 1,  # (..., cache_len)
 }
 
-#: pool leaves whose page axis is followed by a head axis (page, head, off, ...)
-_HEAD_MAJOR_POOLS = ("k", "v", "k_scale", "v_scale")
+#: leaves with a head axis ahead of the sequence axis in the dense layout
+#: (batch, head, seq, ...) and in the view attention reads.  Their paged
+#: pools store each token's heads contiguous: every pool is
+#: (num_pages, page_size, token features...), and k/v flatten heads x dim
+#: into one feature axis — the layout the programs scatter tokens into and
+#: gather pages from, and the TPU's default layout for that shape, so no
+#: program re-lays the pool out
+_HEAD_MAJOR = ("k", "v", "k_scale", "v_scale")
 
 #: reserved physical page id: write sink for masked/pad scatters, never read
 TRASH_PAGE = 0
+
+#: positions per slot that one round of a window write scatters
+#: (:func:`_window_rounds`)
+_WINDOW_ROWS = 32
 
 LAYOUTS = ("dense", "paged")
 
@@ -137,9 +153,10 @@ def attention_cache_spec(
     the KV cache, KIVI-style).
 
     Paged: k/v (or latent) pools keyed by physical page —
-    (num_pages, Hkv, page_size, D) / (num_pages, page_size, width) —
+    (num_pages, page_size, Hkv * D) / (num_pages, page_size, width) —
     plus a per-slot ``page_table`` (batch, max_len // page_size) of
-    physical page ids.  Scale pools mirror their data pools per page.
+    physical page ids.  Scale pools mirror their data pools per page:
+    (num_pages, page_size, Hkv) / (num_pages, page_size).
     """
     if layout not in LAYOUTS:
         raise ValueError(f"unknown kv layout {layout!r}; use one of {LAYOUTS}")
@@ -223,21 +240,15 @@ def _paged_attention_cache_spec(
     else:
         hd = cfg.resolved_head_dim
         kv_dtype = jnp.int8 if quantized else dtype
+        rows = (num_pages, page_size, cfg.n_kv_heads * hd)
         spec = {
-            "k": jax.ShapeDtypeStruct(
-                (num_pages, cfg.n_kv_heads, page_size, hd), kv_dtype
-            ),
-            "v": jax.ShapeDtypeStruct(
-                (num_pages, cfg.n_kv_heads, page_size, hd), kv_dtype
-            ),
+            "k": jax.ShapeDtypeStruct(rows, kv_dtype),
+            "v": jax.ShapeDtypeStruct(rows, kv_dtype),
         }
         if quantized:
-            spec["k_scale"] = jax.ShapeDtypeStruct(
-                (num_pages, cfg.n_kv_heads, page_size), jnp.float32
-            )
-            spec["v_scale"] = jax.ShapeDtypeStruct(
-                (num_pages, cfg.n_kv_heads, page_size), jnp.float32
-            )
+            scales = (num_pages, page_size, cfg.n_kv_heads)
+            spec["k_scale"] = jax.ShapeDtypeStruct(scales, jnp.float32)
+            spec["v_scale"] = jax.ShapeDtypeStruct(scales, jnp.float32)
     spec["page_table"] = table
     return spec
 
@@ -333,8 +344,9 @@ def cache_logical_axes(
 ) -> PyTree:
     """Logical axes for cache sharding (distributed/sharding.py).
 
-    Paged pools have no batch axis — they shard over heads (TP) with the
-    page axis replicated; the tiny page table shards over batch.
+    Paged pools have no batch axis — they shard over heads (TP; a k/v
+    row is head-major, so a split of it is a split of the heads) with
+    the page axis replicated; the tiny page table shards over batch.
     """
     if layout == "paged":
         if cfg.attn_kind == "mla":
@@ -343,12 +355,12 @@ def cache_logical_axes(
                 per_layer["latent_scale"] = ("layers", None, None)
         else:
             per_layer = {
-                "k": ("layers", None, "kv_heads", None, None),
-                "v": ("layers", None, "kv_heads", None, None),
+                "k": ("layers", None, None, "kv_heads"),
+                "v": ("layers", None, None, "kv_heads"),
             }
             if quantized:
-                per_layer["k_scale"] = ("layers", None, "kv_heads", None)
-                per_layer["v_scale"] = ("layers", None, "kv_heads", None)
+                per_layer["k_scale"] = ("layers", None, None, "kv_heads")
+                per_layer["v_scale"] = ("layers", None, None, "kv_heads")
         per_layer["page_table"] = ("layers", "batch", None)
         return {"layers": per_layer}
     kind = "mamba" if cfg.family in ("ssm", "hybrid") else cfg.attn_kind
@@ -381,8 +393,12 @@ def cache_logical_axes(
 
 
 # ---------------------------------------------------------------------------
-# Traced helpers: paged decode read/write views (used by models/attention.py)
+# Traced helpers: cache read/write views (used by models/attention.py)
 # ---------------------------------------------------------------------------
+
+#: key of the layer index in a cache handed to one layer as the whole
+#: stacked cache (see :func:`layer_cache`)
+LAYER = "layer"
 
 
 def is_paged(cache: dict | None) -> bool:
@@ -390,8 +406,45 @@ def is_paged(cache: dict | None) -> bool:
     return cache is not None and "page_table" in cache
 
 
-def _pool_page_size(name: str, pool: jax.Array) -> int:
-    return pool.shape[2] if name in _HEAD_MAJOR_POOLS else pool.shape[1]
+def rides_layer_carry(group: dict | None) -> bool:
+    """Whether a stacked cache group is updated in place through the
+    layer loop.
+
+    Position-addressed caches (paged pools, dense slabs, a prefill's
+    scratch slab) ride the layer scan's carry: each layer writes its
+    tokens into, and reads its pages from, the stacked buffers at its
+    own layer index, so no op materialises a whole layer's pool.
+    State-sized caches (SSM state, rolling sliding-window buffers) are
+    sliced per layer instead."""
+    if not group:
+        return False
+    return "page_table" in group or (
+        "slot_pos" not in group
+        and any(name in SEQ_AXIS_FROM_RIGHT for name in group)
+    )
+
+
+def layer_cache(stacked: dict, layer: jax.Array) -> dict:
+    """The cache one layer addresses in place: every stacked leaf
+    (leading layer axis) plus the layer's index.  The helpers below take
+    either form, so attention code never knows which one it holds."""
+    return {**stacked, LAYER: layer}
+
+
+def stacked_cache(cache: dict) -> dict:
+    """The stacked leaves of a :func:`layer_cache`, its index dropped."""
+    return {k: v for k, v in cache.items() if k != LAYER}
+
+
+def _lead(cache: dict) -> tuple:
+    """Index prefix that selects the cache's own layer: ``(layer,)`` for
+    a :func:`layer_cache`, ``()`` for a per-layer cache."""
+    return (cache[LAYER],) if LAYER in cache else ()
+
+
+def _page_table(cache: dict) -> jax.Array:
+    table = cache["page_table"]
+    return table[cache[LAYER]] if LAYER in cache else table
 
 
 def paged_decode_write(
@@ -405,20 +458,108 @@ def paged_decode_write(
     Retired slots have all-trash page tables, so their (frozen) writes
     land in the reserved trash page and never alias live data.
     """
-    table = cache["page_table"]  # (B, pages_per_slot)
+    table = _page_table(cache)  # (B, pages_per_slot)
+    lead = _lead(cache)
     out = dict(cache)
     for name, val in updates.items():
         pool = cache[name]
-        ps = _pool_page_size(name, pool)
+        ps = pool.shape[len(lead) + 1]
         phys = jnp.take_along_axis(
             table, (positions // ps)[:, None], axis=1
         )[:, 0]  # (B,)
         off = positions % ps
-        if name in _HEAD_MAJOR_POOLS:
-            out[name] = pool.at[phys, :, off].set(val.astype(pool.dtype))
-        else:
-            out[name] = pool.at[phys, off].set(val.astype(pool.dtype))
+        row = val.reshape(val.shape[:1] + pool.shape[len(lead) + 2:])
+        out[name] = pool.at[lead + (phys, off)].set(row.astype(pool.dtype))
     return out
+
+
+def dense_decode_write(
+    cache: dict, updates: dict[str, jax.Array], slots: jax.Array
+) -> dict:
+    """Scatter one token per slot into a dense cache.
+
+    Same ``updates`` shapes as :func:`paged_decode_write`; ``slots``:
+    (B,) sequence indices to write (the position, or its rolling-buffer
+    slot)."""
+    lead = _lead(cache)
+    out = dict(cache)
+    b = slots.shape[0]
+    for name, val in updates.items():
+        buf = cache[name]
+        if name in _HEAD_MAJOR:
+            idx = (
+                jnp.arange(b)[:, None],
+                jnp.arange(val.shape[1])[None, :],
+                slots[:, None],
+            )
+        else:
+            idx = (jnp.arange(b), slots)
+        out[name] = buf.at[lead + idx].set(val.astype(buf.dtype))
+    return out
+
+
+def dense_prefill_write(cache: dict, updates: dict[str, jax.Array]) -> dict:
+    """Write each row's leading prompt positions into a dense cache (a
+    prefill's fresh scratch slab).  ``updates``: leaf name -> per-slot
+    prompt windows from position 0 (k/v: (B, Hkv, S, D); scales:
+    (B, Hkv, S); latent: (B, S, width); latent_scale: (B, S))."""
+    lead = _lead(cache)
+    out = dict(cache)
+    for name, val in updates.items():
+        buf = cache[name]
+        val = val.astype(buf.dtype)
+        start = lead + (0,) * val.ndim
+        if lead:
+            val = val[None]
+        out[name] = jax.lax.dynamic_update_slice(buf, val, start)
+    return out
+
+
+def _window_rounds(positions, rows, length):
+    """Pad a window to whole rounds of ``_WINDOW_ROWS`` positions per slot
+    (padding sits at ``length``, past every slot's rows, and is dropped)
+    and count the rounds up to the last live position.  On the TPU each
+    row a scatter is handed costs a copy into the cache whether it lands
+    or is dropped, and a window is mostly padding, so the window writes
+    scatter round by round and stop there."""
+    w = positions.shape[1]
+    size = min(_WINDOW_ROWS, w)
+    pad = -w % size
+    if pad:
+        positions = jnp.pad(
+            positions, ((0, 0), (0, pad)), constant_values=length
+        )
+        rows = {
+            name: jnp.pad(r, ((0, 0), (0, pad)) + ((0, 0),) * (r.ndim - 2))
+            for name, r in rows.items()
+        }
+    live_end = jnp.max(
+        jnp.where(positions < length, jnp.arange(w + pad) + 1, 0)
+    )
+    return positions, rows, size, (live_end + size - 1) // size
+
+
+def _scatter_rounds(cache, rows, positions, length, index):
+    """Scatter (B, W, ...) window ``rows`` into ``cache`` round by round
+    (:func:`_window_rounds`); ``index(name, p)`` maps one round's (B, n)
+    positions to the leaf's scatter index after the layer prefix."""
+    lead = _lead(cache)
+    positions, rows, size, rounds = _window_rounds(positions, rows, length)
+
+    def write_round(i, pools):
+        at = i * size
+        p = jax.lax.dynamic_slice_in_dim(positions, at, size, axis=1)
+        return {
+            name: pool.at[lead + index(name, p)].set(
+                jax.lax.dynamic_slice_in_dim(rows[name], at, size, axis=1)
+                .astype(pool.dtype),
+                mode="drop",
+            )
+            for name, pool in pools.items()
+        }
+
+    pools = {name: cache[name] for name in rows}
+    return {**cache, **jax.lax.fori_loop(0, rounds, write_round, pools)}
 
 
 def dense_window_write(
@@ -435,21 +576,22 @@ def dense_window_write(
     positions; masked entries carry an out-of-range sentinel
     (>= cache length) and are dropped by the scatter.
     """
-    out = dict(cache)
     b = positions.shape[0]
-    for name, val in updates.items():
-        buf = cache[name]
-        if name in _HEAD_MAJOR_POOLS:
-            bi = jnp.arange(b)[:, None, None]
-            hi = jnp.arange(buf.shape[1])[None, :, None]
-            out[name] = buf.at[bi, hi, positions[:, None, :]].set(
-                val.astype(buf.dtype), mode="drop"
-            )
-        else:
-            out[name] = buf.at[jnp.arange(b)[:, None], positions].set(
-                val.astype(buf.dtype), mode="drop"
-            )
-    return out
+    rows = {
+        name: jnp.moveaxis(val, 2, 1) if name in _HEAD_MAJOR else val
+        for name, val in updates.items()
+    }  # (B, W, ...)
+    first = next(iter(updates))
+    length = cache[first].shape[-SEQ_AXIS_FROM_RIGHT[first]]
+
+    def index(name, p):
+        if name in _HEAD_MAJOR:  # rows (B, n, H[, D]) into (B, H, S[, D])
+            heads = jnp.arange(rows[name].shape[2])
+            return (jnp.arange(b)[:, None, None], heads[None, None, :],
+                    p[:, :, None])
+        return (jnp.arange(b)[:, None], p)
+
+    return _scatter_rounds(cache, rows, positions, length, index)
 
 
 def paged_window_write(
@@ -460,31 +602,43 @@ def paged_window_write(
     Same update shapes and (B, W) ``positions`` contract as
     :func:`dense_window_write`.  Each position routes through the page
     table independently, so a window may straddle page boundaries.
-    Sentinel positions index past the table and are routed to the
-    reserved trash page (same write-sink convention as retired slots in
-    :func:`paged_decode_write`), so masked entries never alias live
-    data.
+    Sentinel positions index past the table and are dropped, never
+    written, so masked entries never alias live data.
     """
-    table = cache["page_table"]  # (B, pages_per_slot)
-    out = dict(cache)
+    table = _page_table(cache)  # (B, pages_per_slot)
+    lead = _lead(cache)
+    rows = {}
     for name, val in updates.items():
-        pool = cache[name]
-        ps = _pool_page_size(name, pool)
+        if name in _HEAD_MAJOR:
+            val = jnp.moveaxis(val, 2, 1)  # (B, W, H[, D])
+        feat = cache[name].shape[len(lead) + 2:]
+        rows[name] = val.reshape(val.shape[:2] + feat)
+    # every pool is (pages, page_size, ...) after the layer prefix
+    past, ps = cache[next(iter(updates))].shape[len(lead):len(lead) + 2]
+
+    def index(name, p):  # a position past the table goes past the pool
         phys = jnp.take_along_axis(
-            table, positions // ps, axis=1,
-            mode="fill", fill_value=TRASH_PAGE,
-        )  # (B, W)
-        off = positions % ps
-        if name in _HEAD_MAJOR_POOLS:
-            # advanced-index axes lead the result: updates go (B, W, H[, D])
-            v = jnp.moveaxis(val, 2, 1)
-            out[name] = pool.at[phys, :, off].set(v.astype(pool.dtype))
-        else:
-            out[name] = pool.at[phys, off].set(val.astype(pool.dtype))
-    return out
+            table, p // ps, axis=1, mode="fill", fill_value=past
+        )
+        return (phys, p % ps)
+
+    return _scatter_rounds(
+        cache, rows, positions, table.shape[1] * ps, index
+    )
 
 
-def paged_decode_view(cache: dict) -> dict[str, jax.Array]:
+def window_write(
+    cache: dict, updates: dict[str, jax.Array], positions: jax.Array
+) -> dict:
+    """:func:`paged_window_write` or :func:`dense_window_write`, by the
+    cache's layout."""
+    write = paged_window_write if is_paged(cache) else dense_window_write
+    return write(cache, updates, positions)
+
+
+def paged_decode_view(
+    cache: dict, kv_heads: int | None = None
+) -> dict[str, jax.Array]:
     """Gather each slot's pages into a contiguous logical view.
 
     Returns dense-shaped arrays — k/v: (B, Hkv, L, D); scales:
@@ -492,21 +646,48 @@ def paged_decode_view(cache: dict) -> dict[str, jax.Array]:
     ``L = pages_per_slot * page_size == max_seq_len``, so downstream
     attention math is bit-identical to the dense layout (unallocated
     entries read the trash page and are masked by position validity,
-    exactly like dense positions beyond the write head).
+    exactly like dense positions beyond the write head).  ``kv_heads``
+    splits a k/v row back into its heads (GQA pools).  A
+    :func:`layer_cache` gathers straight from the stacked pool at
+    ``[layer, table]``.
     """
-    table = cache["page_table"]  # (B, pages_per_slot)
+    table = _page_table(cache)  # (B, pages_per_slot)
+    lead = _lead(cache)
     out = {}
     for name, pool in cache.items():
-        if name == "page_table":
+        if name in ("page_table", LAYER):
             continue
-        g = pool[table]  # (B, n_pages, ...)
-        if name in _HEAD_MAJOR_POOLS:
-            g = jnp.moveaxis(g, 2, 1)  # (B, Hkv, n_pages, ps[, D])
-            shape = g.shape[:2] + (g.shape[2] * g.shape[3],) + g.shape[4:]
-        else:
-            shape = (g.shape[0], g.shape[1] * g.shape[2]) + g.shape[3:]
-        out[name] = g.reshape(shape)
+        g = pool[lead + (table,)]  # (B, n_pages, ps, ...)
+        g = g.reshape((g.shape[0], g.shape[1] * g.shape[2]) + g.shape[3:])
+        if name in _HEAD_MAJOR:
+            dim = (-1,) if SEQ_AXIS_FROM_RIGHT[name] == 2 else ()
+            g = g.reshape(g.shape[:2] + (kv_heads,) + dim)
+            g = jnp.moveaxis(g, 2, 1)  # (B, Hkv, L[, D])
+        out[name] = g
     return out
+
+
+def decode_view(
+    cache: dict, kv_heads: int | None = None
+) -> dict[str, jax.Array]:
+    """The contiguous per-slot view one layer's attention reads: the
+    page gather of :func:`paged_decode_view`, or the dense slab itself
+    (its own layer's, for a :func:`layer_cache`)."""
+    if is_paged(cache):
+        return paged_decode_view(cache, kv_heads)
+    if LAYER not in cache:
+        return cache
+    layer = cache[LAYER]
+    return {k: v[layer] for k, v in cache.items() if k != LAYER}
+
+
+def pool_leaf(caches: PyTree) -> jax.Array:
+    """One storage leaf of the stacked model caches (never the page
+    table): a program that consumed its caches argument deleted it."""
+    return next(
+        leaf for name, leaf in caches["layers"].items()
+        if name != "page_table"
+    )
 
 
 def decode_read_len(caches: PyTree) -> int:
@@ -611,7 +792,11 @@ def insert_prefill_paged(
         paged_shape = (
             small.shape[:axis] + (n_pages, page_size) + small.shape[axis + 1:]
         )
-        pages = jnp.moveaxis(small.reshape(paged_shape), axis, 2)
+        pages = small.reshape(paged_shape)  # (L, N, [H,] n_pages, ps, ...)
+        if name in _HEAD_MAJOR:
+            pages = jnp.moveaxis(pages, 2, axis + 1)  # heads after the offset
+        # (L, N, n_pages, ps) + the pool's token features
+        pages = pages.reshape(pages.shape[:4] + pool.shape[3:])
         # pool (L, P, ...), indices (N, n_pages) on axis 1
         layers[name] = pool.at[:, row_tables[:, :n_pages]].set(
             pages.astype(pool.dtype)

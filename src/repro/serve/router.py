@@ -46,6 +46,8 @@ _SUMMED = (
     "tokens_generated",
     "prefill_dispatches",
     "extend_dispatches",
+    "decode_dispatches",
+    "kv_pool_donated",
     "prompts_admitted",
     "preemptions",
     "deadline_requests",

@@ -1,8 +1,9 @@
 """KV-cache subsystem tests: dense-vs-paged token parity across GQA / MLA /
 int8-KV configs, slot churn (admit/finish/re-admit) with page reuse and no
 cross-request leakage, the jit program budget (len(prefill_buckets) prefill
-+ 1 decode, both layouts), CacheManager allocation bookkeeping, and
-sharding composition for paged pools."""
++ 1 decode, both layouts), CacheManager allocation bookkeeping,
+sharding composition for paged pools, and every program consuming
+(donating) the pool it is handed."""
 
 import dataclasses
 
@@ -15,7 +16,7 @@ from repro import configs
 from repro.configs.base import ServeConfig
 from repro.core import precision as P
 from repro.models import lm
-from repro.serve import CacheManager, ServingEngine
+from repro.serve import CacheManager, Engine, ServingEngine
 from repro.serve import kv_cache as kvc
 
 KEY = jax.random.PRNGKey(11)
@@ -78,6 +79,83 @@ def test_dense_paged_token_identical(arch, policy, temperature):
         )
         assert eng.kv_layout == layout
     assert outs["dense"] == outs["paged"]
+
+
+def _dispatches(tel):
+    """Program dispatches that take the KV pool."""
+    return (
+        tel["prefill_dispatches"] + tel["extend_dispatches"]
+        + tel["decode_dispatches"] + tel["spec_dispatches"]
+    )
+
+
+def _waves(cfg, params, serve_cfg, waves, n_new=6):
+    eng = Engine(cfg, params, serve_cfg, seed=0)
+    outs = []
+    for wave in waves:
+        handles = [eng.submit(list(p), max_new_tokens=n_new) for p in wave]
+        fin = eng.generate()
+        outs.extend(fin[h.uid].generated for h in handles)
+    return eng, outs
+
+
+def test_dense_paged_token_identical_through_cow_and_swaps():
+    """The parity prompts again, then four tenants whose 3-page preambles
+    outgrow a 12-page pool (evicted pages spill to the host tier and swap
+    back on the next visit), then exact repeats of a preamble (a
+    full-coverage hit whose first decode write copies the shared page).
+    Every program consumes the pool it is handed and the copies and swaps
+    land between dispatches, yet the paged streams equal the dense ones."""
+    cfg = configs.get_config("granite-8b", reduced=True)
+    params = _params(cfg)
+    rng = np.random.default_rng(3)
+    tenants = [list(rng.integers(0, cfg.vocab_size, 24)) for _ in range(4)]
+    waves = [PROMPTS[:2], PROMPTS[2:]]
+    waves += [
+        [tenants[(2 * w + j) % 4] + [w + 1, j + 1] for j in range(2)]
+        for w in range(6)
+    ]
+    waves += [[tenants[1]] * 2, [tenants[3]] * 2]
+    _, dense = _waves(cfg, params, _serve("dense"), waves)
+    eng, paged = _waves(
+        cfg, params,
+        _serve("paged", kv_pages=13, kv_prefix_cache=True,
+               kv_preemption=True, kv_host_pages=32),
+        waves,
+    )
+    assert paged == dense
+    tel = eng.telemetry
+    assert tel["cow_copies"] > 0 and tel["swap_ins"] > 0
+    assert tel["kv_pool_donated"] == _dispatches(tel)
+    eng.executor.cache_mgr.check_invariants()
+
+
+@pytest.mark.parametrize("policy", [None, KV8])
+@pytest.mark.parametrize("async_loop", [False, True])
+def test_every_dispatch_consumes_the_kv_pool(policy, async_loop):
+    """Each program takes the pool it is handed (donation) and the engine
+    keeps only the program's output: after every step that dispatched,
+    the pool the step started from is deleted, and ``kv_pool_donated``
+    counts every prefill, extend and decode dispatch.  Repeated prompts
+    hit the prefix cache; on the int8-KV datapath their tails replay
+    through the extend program."""
+    cfg = configs.get_config("granite-8b", reduced=True)
+    eng = Engine(cfg, _params(cfg), _serve(
+        "paged", policy=policy, async_loop=async_loop,
+        kv_prefix_cache=True, prefill_buckets=(8, 16),
+    ))
+    for p in PROMPTS + PROMPTS:
+        eng.submit(list(p), max_new_tokens=6)
+    while eng.has_work:
+        pool = kvc.pool_leaf(eng.executor.caches)
+        before = _dispatches(eng.telemetry)
+        eng.step()
+        if _dispatches(eng.telemetry) > before:
+            assert pool.is_deleted()
+    tel = eng.telemetry
+    assert tel["prefill_dispatches"] > 0 and tel["decode_dispatches"] > 0
+    assert tel["extend_dispatches"] > 0 or policy is None
+    assert tel["kv_pool_donated"] == _dispatches(tel)
 
 
 def test_unpageable_families_fall_back_to_dense():
@@ -280,9 +358,9 @@ def test_paged_spec_shapes_gqa_and_mla():
         page_size=16, num_pages=9,
     )
     hd = gqa.resolved_head_dim
-    assert spec["k"].shape == (9, gqa.n_kv_heads, 16, hd)
+    assert spec["k"].shape == (9, 16, gqa.n_kv_heads * hd)
     assert spec["k"].dtype == jnp.int8
-    assert spec["k_scale"].shape == (9, gqa.n_kv_heads, 16)
+    assert spec["k_scale"].shape == (9, 16, gqa.n_kv_heads)
     assert spec["page_table"].shape == (4, 4)
     mla = configs.get_config("minicpm3-4b", reduced=True)
     spec = kvc.attention_cache_spec(
@@ -328,7 +406,7 @@ def test_paged_roundtrip_write_view():
     cache = kvc.paged_decode_write(
         cache, {"k": k_new, "v": v_new}, positions
     )
-    view = kvc.paged_decode_view(cache)
+    view = kvc.paged_decode_view(cache, cfg.n_kv_heads)
     assert view["k"].shape == (2, cfg.n_kv_heads, 32, hd)
     np.testing.assert_array_equal(np.asarray(view["k"][0, :, 3]), k_new[0])
     np.testing.assert_array_equal(np.asarray(view["k"][1, :, 11]), k_new[1])
@@ -336,6 +414,47 @@ def test_paged_roundtrip_write_view():
     # everything else is still zero
     assert float(jnp.abs(view["k"][0, :, 4:]).max()) == 0.0
     assert float(jnp.abs(view["k"][1, :, :11]).max()) == 0.0
+
+
+@pytest.mark.parametrize("stacked", [False, True])
+@pytest.mark.parametrize("layout", ["dense", "paged"])
+def test_window_write_rounds_match_one_scatter(layout, stacked):
+    """A window wider than one scatter round (70 positions: three rounds,
+    the last padded) lands exactly the live positions of each row —
+    a mid-page start, a masked-out row, a row live to the last column —
+    and nothing else, per layer or addressed in the stacked cache."""
+    cfg = configs.get_config("granite-8b", reduced=True)
+    b, w, hd = 3, 70, cfg.resolved_head_dim
+    kw = dict(layout="paged", page_size=8, num_pages=49) if layout == "paged" else {}
+    cache = kvc.init_attention_cache(cfg, b, 128, dtype=jnp.float32, **kw)
+    if layout == "paged":  # slot i owns pages 1 + 16 i ..
+        cache["page_table"] = 1 + jnp.arange(b * 16, dtype=jnp.int32).reshape(b, 16)
+    rng = np.random.default_rng(1)
+    base = {
+        n: jnp.asarray(rng.normal(size=leaf.shape), jnp.float32)
+        for n, leaf in cache.items() if n != "page_table"
+    }
+    cache.update(base)
+    starts, lens = np.array([5, 0, 30]), np.array([50, 0, 70])
+    t = np.arange(w)
+    positions = np.where(t < lens[:, None], starts[:, None] + t, 128)
+    upd = {
+        n: jnp.asarray(rng.normal(size=(b, cfg.n_kv_heads, w, hd)), jnp.float32)
+        for n in ("k", "v")
+    }
+    want = {n: np.array(v) for n, v in kvc.decode_view(cache, cfg.n_kv_heads).items()}
+    for n in upd:
+        for i in range(b):
+            want[n][i][:, starts[i]:starts[i] + lens[i]] = np.asarray(upd[n][i])[:, :lens[i]]
+    if stacked:  # layer 1 of two, addressed in place
+        two = {n: jnp.stack([jnp.zeros_like(x), x]) for n, x in cache.items()}
+        out = kvc.window_write(kvc.layer_cache(two, 1), upd, jnp.asarray(positions))
+        assert all(float(jnp.abs(x[0]).max()) == 0 for n, x in kvc.stacked_cache(out).items() if n != "page_table")
+    else:
+        out = kvc.window_write(cache, upd, jnp.asarray(positions))
+    got = kvc.decode_view(out, cfg.n_kv_heads)
+    for n in upd:
+        np.testing.assert_array_equal(np.asarray(got[n]), want[n])
 
 
 # --------------------------------------------------------- sharding -------

@@ -1,5 +1,6 @@
 """Compile the main-path Pallas kernels for a described TPU v5e chip at
-minicpm-2b widths (d_model 2304, 36 heads of 64, d_ff 5760).
+minicpm-2b widths (d_model 2304, 36 heads of 64, d_ff 5760), and the
+engine's decode scan and extend program at each benchmark cell's widths.
 
 Nothing runs: the TPU compiler is handed shapes for a chip that is
 described, not attached, and refuses what Mosaic would refuse on the
@@ -9,10 +10,14 @@ a fixture, never at import: only one process may load the TPU library,
 and every test worker imports this file.
 """
 
+import json
 import os
+import pathlib
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -88,3 +93,113 @@ def test_qmatmul_compiles(one_chip):
     x = jax.ShapeDtypeStruct((SEQ, D_MODEL), jnp.float32, sharding=one_chip)
     w = jax.ShapeDtypeStruct((D_MODEL, D_FF), jnp.float32, sharding=one_chip)
     assert "tpu_custom_call" in _hlo(qmatmul, x, w)
+
+
+# ------------------------------------------- the KV pool is updated in place --
+
+CONFIGS = pathlib.Path(__file__).resolve().parents[1] / "bench" / "configs"
+
+#: ops that only pass a buffer along, and the in-place token write
+_POOL_OPS_ALLOWED = {"parameter", "get-tuple-element", "tuple", "bitcast",
+                     "scatter"}
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%(\S+) .*\{$")
+_INSTRUCTION = re.compile(
+    r"^\s+(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\S* ([\w-]+)\((.*)$"
+)
+_CALLS = re.compile(r"calls=%([^,\s]+)")
+
+
+def _computations(hlo: str) -> dict[str, list[tuple[str, tuple, str, str]]]:
+    """computation name -> its instructions (name, shape, opcode, rest)."""
+    comps, cur = {}, None
+    for line in hlo.splitlines():
+        m = _COMPUTATION.match(line)
+        if m:
+            cur = comps.setdefault(m.group(1), [])
+            continue
+        m = _INSTRUCTION.match(line)
+        if m and cur is not None:
+            shape = tuple(int(x) for x in m.group(2).split(",") if x)
+            cur.append((m.group(1), shape, m.group(3), m.group(4)))
+    return comps
+
+
+def _pool_producers(hlo: str, pool_shapes: set) -> list[str]:
+    """Ops outside fused computations whose result is shaped like a whole
+    stacked pool or one layer's pool, other than pass-through ops and the
+    in-place scatter (a fusion counts as the op at its root)."""
+    comps = _computations(hlo)
+    root_op = {name: ins[-1][2] for name, ins in comps.items() if ins}
+    fused = {}  # fusion instruction -> the computation it calls
+    for instructions in comps.values():
+        for name, _, op, rest in instructions:
+            called = _CALLS.search(rest)
+            if op == "fusion" and called:
+                fused[name] = called.group(1)
+    found = []
+    for comp, instructions in comps.items():
+        if comp in fused.values():
+            continue
+        for name, shape, op, _ in instructions:
+            if shape not in pool_shapes:
+                continue
+            op = root_op.get(fused.get(name), op)
+            if op not in _POOL_OPS_ALLOWED:
+                found.append(f"{comp}: %{name} {op} {list(shape)}")
+    return found
+
+
+@pytest.mark.parametrize("name", ["minicpm-2b", "granite-8b-l18"])
+def test_kv_pool_is_updated_in_place(one_chip, monkeypatch, name):
+    """The decode scan and the extend program write tokens into the stacked
+    pool and read pages from it where it lies: no op copies, slices out or
+    writes back a whole pool or a whole layer's pool, the pool enters in
+    the layout the program works in, and the output aliases it (donation).
+    Each cell's engine at 2 of its layers, pool at its own page count."""
+    from bench import harness
+    from repro.models import lm
+    from repro.serve import kv_cache
+    from repro.serve.executor import ModelExecutor
+
+    cfg = json.loads((CONFIGS / f"{name}.json").read_text())
+    cfg["model"]["num_hidden_layers"] = 2
+    mc = harness.model_config(cfg)
+    sc = harness.serve_config(cfg)
+    # shapes only: nothing is allocated for a chip that is only described
+    monkeypatch.setattr(
+        kv_cache.CacheManager, "init_device_caches",
+        lambda self: self._abstract(),
+    )
+    ex = ModelExecutor(mc, lm.abstract_params(mc), sc)
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+            tree,
+        )
+
+    params, caches = on_chip(ex.params), on_chip(ex.caches)
+    pools = [
+        leaf for key, leaf in caches["layers"].items() if key != "page_table"
+    ]
+    pool_shapes = {p.shape for p in pools} | {p.shape[1:] for p in pools}
+    pool_bytes = sum(int(np.prod(p.shape)) * p.dtype.itemsize for p in pools)
+
+    nb, steps, w = sc.max_batch, sc.decode_steps, ex.extend_width
+    i32, f32 = jnp.int32, jnp.float32
+
+    def arg(shape, dtype=i32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    key = arg((2,), jnp.uint32)
+    decode = ex._decode_fn.lower(
+        params, arg((nb,)), arg((nb,)), arg((nb,), bool), arg((nb,)),
+        arg((nb,)), arg((nb,), f32), arg((nb,)), arg((nb,), f32),
+        arg((nb,)), arg((steps, nb)), arg((nb,)), caches, key,
+    ).compile()
+    extend = ex._extend_fn.lower(
+        params, arg((nb, w)), arg((nb,)), arg((nb,)), caches
+    ).compile()
+    for program in (decode, extend):
+        assert _pool_producers(program.as_text(), pool_shapes) == []
+        assert program.memory_analysis().alias_size_in_bytes >= pool_bytes
