@@ -9,8 +9,8 @@
 Each run is ``bench.harness.run_cell`` as ``bench/run.py`` makes it, and
 prints one JSON line with the seed, the rate, ``correct``, the numbers
 compared, the metrics and the diagnostics.  ``--control`` judges the
-check's lower-precision control (``w8`` or ``w8a8``, bench/reference.py)
-in the program's place; ``--engine`` overrides ServeConfig fields of the
+check's lower-precision control (``w8`` or ``w8a8``, the architecture's
+reference in ``bench/arch``) in the program's place; ``--engine`` overrides ServeConfig fields of the
 cell's configuration; ``--rates`` sweeps an open-loop mix's arrival rate to find the
 knee, each rate on its own seeds with ``--seed-stride`` (rate i adds i
 times the stride to each seed); ``--spec-root`` reads BENCHMARK.json and its data files from

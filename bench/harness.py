@@ -1,10 +1,12 @@
-"""Runs one cell of ``BENCHMARK.json``: finds its configuration, traffic mix
-and metrics by name, serves the traffic through ``Engine`` on the chip,
+"""Runs one cell of ``BENCHMARK.json``: finds its configuration, the
+configuration's architecture (``bench/arch/<name>.py``), traffic mix and
+metrics by name, serves the traffic through ``Engine`` on the cell's chips,
 and checks what was served against the plain reference.
 
 A run, in order:
 
-1. set-up: weights from the seed in one jitted call, the engine, the
+1. set-up: weights from the seed in one jitted call, split over the cell's
+   chips where it spans several (``bench/weights.py``), the engine, the
    mix's warm-up requests (every program the mix uses, compiled or loaded
    from the persistent cache), then a lead-in of the same traffic that
    fills the batch and the prefix cache;
@@ -15,7 +17,8 @@ A run, in order:
 3. the drain: requests due in the window finish (at most ``drain_s``);
 4. the check: a seeded sample of the finished requests, the longest among
    them, is run through the float32 reference with the weights drawn
-   again, after the engine is freed.
+   again, after the engine is freed, one layer at a time whole on each of
+   the cell's chips.
 
 Metrics are readers in ``bench/metrics/<name>.py``: ``read(rec)`` returns a
 number, or None where the run gave it nothing to read.
@@ -24,6 +27,7 @@ number, or None where the run gave it nothing to read.
 from __future__ import annotations
 
 import gc
+import importlib
 import importlib.util
 import json
 import math
@@ -31,7 +35,7 @@ import shutil
 import sys
 import tempfile
 from pathlib import Path
-from types import SimpleNamespace
+from types import ModuleType, SimpleNamespace
 
 import numpy as np
 
@@ -56,8 +60,10 @@ class Spec:
 
     def __init__(self, root: Path = ROOT, data: Path | None = None):
         self.root = root
-        #: where the traffic mixes are (``<data>/traffic/<name>.json``)
-        self.traffic_dir = (data or root / "bench") / "traffic"
+        #: where the traffic mixes are (``<data>/traffic/<name>.json``),
+        #: and architectures beside the benchmark's own (``<data>/arch``)
+        self.data_dir = data or root / "bench"
+        self.traffic_dir = self.data_dir / "traffic"
         self.data = json.loads((root / "BENCHMARK.json").read_text())
 
     def cell(self, name: str) -> dict:
@@ -84,11 +90,34 @@ class Spec:
                 if cell in m.get("workloads", [cell] if m["moves"] in names else [])]
 
     def reader(self, name: str):
-        path = ROOT / "bench" / "metrics" / f"{name}.py"
-        spec = importlib.util.spec_from_file_location(f"bench_metric_{name}", path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod.read
+        return _load(ROOT / "bench" / "metrics" / f"{name}.py", f"bench_metric_{name}").read
+
+    def arch(self, name: str) -> ModuleType:
+        return arch(name, self.data_dir)
+
+
+def _load(path: Path, name: str) -> ModuleType:
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+#: architectures that a spec's data directory brings, loaded once each
+_ARCHS: dict[Path, ModuleType] = {}
+
+
+def arch(name: str, data: Path | None = None) -> ModuleType:
+    """The architecture module ``bench/arch/<name>.py``, or one that a
+    spec's data directory brings (``<data>/arch/<name>.py``)."""
+    extra = data / "arch" / f"{name}.py" if data else None
+    if extra is not None and extra.is_file():
+        if extra not in _ARCHS:
+            _ARCHS[extra] = _load(extra, f"bench_arch_{len(_ARCHS)}_{name}")
+        return _ARCHS[extra]
+    if (ROOT / "bench" / "arch" / f"{name}.py").is_file():
+        return importlib.import_module(f"bench.arch.{name}")
+    raise KeyError(f"no architecture {name!r}: there is no bench/arch/{name}.py")
 
 
 def peaks(kind: str) -> dict:
@@ -99,34 +128,9 @@ def peaks(kind: str) -> dict:
 
 
 def model_config(cfg: dict):
-    """The engine's ModelConfig for a configuration file (llama-style
-    decoder; MiniCPM's muP keys where the file has them)."""
-    from repro.configs.base import ModelConfig
-
-    m = cfg["model"]
-    if m["hidden_act"] != "silu" or not m["tie_word_embeddings"]:
-        raise ValueError("bench configurations are SwiGLU decoders with tied embeddings")
-    d, layers = m["hidden_size"], m["num_hidden_layers"]
-    return ModelConfig(
-        name=cfg["name"], family="dense", n_layers=layers, d_model=d,
-        n_heads=m["num_attention_heads"], n_kv_heads=m["num_key_value_heads"],
-        d_ff=m["intermediate_size"], vocab_size=m["vocab_size"],
-        attn_kind="gqa", norm_kind="rmsnorm", act="silu", gated_mlp=True,
-        rope_theta=float(m["rope_theta"]), tie_embeddings=True,
-        emb_scale=float(m.get("scale_emb", 1.0)),
-        residual_scale=float(m.get("scale_depth", math.sqrt(layers)) / math.sqrt(layers)),
-        logit_scale=float(m.get("dim_model_base", d)) / d,
-        norm_eps=float(m["rms_norm_eps"]), dtype=m["torch_dtype"],
-    )
-
-
-def dims(mc):
-    from bench.weights import Dims
-
-    return Dims(mc.n_layers, mc.d_model, mc.n_heads, mc.n_kv_heads,
-                mc.resolved_head_dim, mc.d_ff, mc.vocab_size,
-                mc.padded_vocab_size, mc.emb_scale, mc.residual_scale,
-                mc.logit_scale, mc.norm_eps, mc.rope_theta)
+    """The engine's ModelConfig for a configuration file, by its
+    architecture."""
+    return arch(cfg["architecture"]).model_config(cfg)
 
 
 def serve_config(cfg: dict, **overrides):
@@ -216,24 +220,26 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, t_start: float,
              control: str | None = None, require_tpu: bool = True) -> dict:
     """One run of cell ``name``.  The overrides, ``keep_trace`` (copy the
     trace file there) and ``control`` (judge the reference in that lower
-    precision, ``bench/reference.py``, in the program's place) serve
+    precision, the architecture's ``logit_gaps``, in the program's place) serve
     ``bench/calibrate.py`` and the tests; a benchmark run takes the cell as
     ``BENCHMARK.json`` and its files give it."""
     import jax
 
     from bench import client as cl
-    from bench import reference, traffic, weights
+    from bench import traffic, weights
     from bench.trace import load, reduce
     from repro.launch.compile_cache import enable_compile_cache
     from repro.serve import Engine
 
     spec = spec or Spec()
     cell = spec.cell(name)
+    chips = cell["chips"]
     devices = jax.devices()
     dev = devices[0]
-    if require_tpu and (dev.platform != "tpu" or len(devices) < cell["chips"]):
-        raise SystemExit(f"bench: {name} needs {cell['chips']} TPU chip(s), "
+    if len(devices) < chips or (require_tpu and dev.platform != "tpu"):
+        raise SystemExit(f"bench: {name} needs {chips} TPU chip(s), "
                          f"JAX found {len(devices)} x {dev.platform}")
+    place = weights.Place(devices[:chips])
     peak = peaks(dev.device_kind) if require_tpu else None
     enable_compile_cache()
     # cache every program, however quick to compile, so a warm set-up
@@ -245,14 +251,15 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, t_start: float,
     cfg = spec.config(cell["config"])
     mix_spec = json.loads((spec.traffic_dir / f"{cell['traffic']}.json").read_text())
     mix_spec.update(traffic_overrides or {})
-    mc = model_config(cfg)
-    m = dims(mc)
+    arch = spec.arch(cfg["architecture"])
+    mc = arch.model_config(cfg)
+    m = arch.dims(mc)
     sc = serve_config(cfg, **(engine_overrides or {}))
     embed_std = cfg["weights"]["embed_std"]
     lead_in, drain_s = mix_spec["lead_in_s"], mix_spec["drain_s"]
 
-    w = weights.make(m, seed, embed_std, mc.dtype)
-    eng = Engine(mc, weights.program_tree(w), sc)
+    w = weights.make(arch, m, seed, embed_std, mc.dtype, place)
+    eng = Engine(mc, arch.program_tree(w), sc)
     del w
     mix = traffic.Mix(mix_spec, seed, m.vocab)
     for batch in mix.warmup(sc.prefill_buckets, sc.max_batch):
@@ -313,16 +320,21 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, t_start: float,
     if state.trace == "on":  # the run ended before the traced stretch did
         tick(math.inf)
 
-    # the device's peak as JAX reports it; where the runtime reports none,
-    # the bytes the process holds on the chip now, a lower bound of the peak
-    peak_bytes = (dev.memory_stats() or {}).get("peak_bytes_in_use") or sum(
-        x.nbytes for x in jax.live_arrays() if dev in x.devices())
+    # the fullest chip's peak as JAX reports it; where the runtime reports
+    # none, the bytes the process holds on each chip now, a lower bound of
+    # its peak
+    peak_bytes = max(
+        (d.memory_stats() or {}).get("peak_bytes_in_use") or sum(
+            s.data.nbytes for x in jax.live_arrays() for s in x.addressable_shards
+            if s.device == d)
+        for d in devices[:chips])
     in_window = ([r for r in client.records if w0 <= r.due < w1] if mix.open
                  else [r for r in client.records
                        if r.submitted < w1 and (r.done is None or r.done >= w0)])
     failed = sum(r.done is None for r in in_window)
     rec = SimpleNamespace(
-        dims=m, peak=peak, decode_steps=sc.decode_steps, window=(w0, w1),
+        arch=arch, dims=m, peak=peak, chips=chips, decode_steps=sc.decode_steps,
+        window=(w0, w1),
         requests=in_window, records=client.records, setup_s=w0 - t_start,
         counters=snaps, open=mix.open, prefix_tokens=(
             mix_spec["shared_prefix"]["tokens"] if mix_spec.get("shared_prefix") else 0),
@@ -342,10 +354,11 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, t_start: float,
 
     # the check: weights drawn again, the reference over each sampled
     # prompt with its served tokens
-    w = weights.make(m, seed, embed_std, mc.dtype)
+    w = weights.make(arch, m, seed, embed_std, mc.dtype, place)
     seqs = [(r.req.prompt, r.served) for r in sample]
-    program = reference.logit_gaps(m, w, seqs, sc.max_seq_len)
-    gaps = reference.logit_gaps(m, w, seqs, sc.max_seq_len, control) if control else program
+    program = arch.logit_gaps(m, w, seqs, sc.max_seq_len, place=place)
+    gaps = (arch.logit_gaps(m, w, seqs, sc.max_seq_len, control, place)
+            if control else program)
     del w
     gc.collect()
     served = sum(len(r.served) for r in sample)
@@ -389,6 +402,10 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, t_start: float,
         "counters": snaps, "checked_requests": len(sample),
         "control": control,
         "ttft_ms": _ttft_quantiles(rec) if mix.open else None,
+        # collectives in the traced stretch, seconds per chip: all of them,
+        # and the part in which nothing else ran on the chip
+        "collectives": {"device_s": rec.trace["collective_s"],
+                        "alone_s": rec.trace["collective_alone_s"]} if rec.trace else None,
         "gap_per_request": [float(g.max()) if g.size else 0.0 for g in gaps],
         "gaps": {k: _gap_stats(v) for k, v in (("program", program), ("control", gaps))
                  if served and (k == "program" or control)},

@@ -6,15 +6,19 @@ A reader gets the run record ``rec`` (see ``bench/harness.py``):
 edges, ``rec.counters`` engine counters read at its edges (``w0``, ``w1``)
 and at the traced stretch's (``t0``, ``t1``), ``rec.trace`` the reduced
 trace (``bench/trace.py``) or None, ``rec.trace_interval`` the traced
-stretch on the host clock (after the window), ``rec.dims`` the configuration's shapes and
-``rec.peak`` the chip's row of ``bench/peaks.json``.
+stretch on the host clock (after the window), ``rec.arch`` the architecture's
+module (``bench/arch``), which counts the work, ``rec.dims`` the configuration's
+shapes, ``rec.peak`` one chip's row of ``bench/peaks.json`` and ``rec.chips``
+the chips the cell spans.
+
+Shares of a peak are taken against ``rec.chips`` times one chip's peaks, with
+device time the per-chip average that ``bench/trace.py`` gives: a cell that
+uses each of its chips fully reads 100%.
 """
 
 from __future__ import annotations
 
 import math
-
-from bench import work
 
 
 def percentile(values, p: float) -> float | None:
@@ -67,19 +71,26 @@ def prefill_work(rec) -> tuple[float, float]:
     attn = [len(r.req.prompt) - (rec.prefix_tokens if r.req.tenant >= 0 else 0)
             for r in firsts]
     n, _ = program(rec, "prefill", "extend")
-    return work.prefill(rec.dims, new, attn, n)
+    return rec.arch.prefill_work(rec.dims, new, attn, n)
 
 
 def decode_work(rec) -> tuple[float, float]:
     n, _ = program(rec, "decode")
-    return work.decode(rec.dims, decode_tokens(rec), n * rec.decode_steps)
+    return rec.arch.decode_work(rec.dims, decode_tokens(rec), n * rec.decode_steps)
+
+
+def least_seconds(rec, flops: float, nbytes: float) -> float:
+    """Roofline time on the cell's chips: the larger of compute time and
+    memory time at ``rec.chips`` times one chip's peaks."""
+    peak = rec.peak
+    return max(flops / peak["bf16_flops_per_s"], nbytes / peak["hbm_bytes_per_s"]) / rec.chips
 
 
 def decode_roofline(rec) -> float | None:
     n, secs = program(rec, "decode")
     if not n or not secs:
         return None
-    return 100 * work.least_seconds(*decode_work(rec), rec.peak) / secs
+    return 100 * least_seconds(rec, *decode_work(rec)) / secs
 
 
 def decode_step_ms(rec) -> float | None:
@@ -89,13 +100,13 @@ def decode_step_ms(rec) -> float | None:
 
 def mfu(rec) -> float | None:
     """Model FLOPs of the prompt and output tokens processed in the traced
-    window over the window's seconds times the chip's peak."""
+    window over the window's seconds times the cell's chips' peak."""
     if not rec.trace:
         return None
     flops = prefill_work(rec)[0] + decode_work(rec)[0]
     if flops <= 0:
         return None
-    return 100 * flops / (rec.trace["window_s"] * rec.peak["bf16_flops_per_s"])
+    return 100 * flops / (rec.trace["window_s"] * rec.chips * rec.peak["bf16_flops_per_s"])
 
 
 def idle_share(rec) -> float | None:

@@ -11,7 +11,9 @@ kinds of event, each as ``(start_ns, end_ns, name)``:
   marks the traced window.
 
 ``reduce`` does the arithmetic on those lists alone, so it is tested on a
-small trace recorded on the chip.
+small trace recorded on the chip.  An operation's event is named by its
+HLO instruction (``"%fusion.12 = f32[...] fusion(...)"``), and XLA names an
+instruction after its opcode, so the collectives are found by name.
 """
 
 from __future__ import annotations
@@ -24,6 +26,18 @@ DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 WINDOW = "bench.window"
 #: program name fragments -> the engine program they stand for
 PROGRAMS = {"_decode_scan": "decode", "_prefill_batch": "prefill", "_extend_batch": "extend"}
+#: collective operations, as the TPU compiler names them in the engine's
+#: programs on a v5e:2x2 mesh: the operations themselves (``%all-gather.151``,
+#: ``%all-reduce.9``, ``%all-to-all.7``), their ``-start``/``-done`` halves,
+#: and the fusions that start and finish an asynchronous one
+#: (``%async-collective-start``, ``%async-collective-done.1``).  A compute
+#: fusion that carries an asynchronous collective (``calls=
+#: %async_collective_fusion.N``) is compute: its time is the work that
+#: overlaps the transfer.
+COLLECTIVE = re.compile(r"%(all-gather|all-reduce|reduce-scatter|collective-permute|all-to-all"
+                        r"|async-collective-start|async-collective-done)")
+#: operations that hold others (a loop's or a call's body runs inside them)
+CONTAINER = re.compile(r"%(while|conditional|call)\b")
 
 
 def load(path: str | Path) -> dict:
@@ -76,6 +90,30 @@ def _union(events) -> list[tuple[float, float]]:
     return [(s, e) for s, e in spans]
 
 
+def _cut(a, b, keep: bool) -> list[tuple[float, float]]:
+    """The parts of the disjoint sorted intervals ``a`` that lie inside
+    (``keep``) or outside the disjoint sorted intervals ``b``."""
+    out, j = [], 0
+    for s, e in a:
+        while j < len(b) and b[j][1] <= s:
+            j += 1
+        t, k = s, j
+        while k < len(b) and b[k][0] < e:
+            bs, be = b[k]
+            if keep:
+                out.append((max(s, bs), min(e, be)))
+            elif bs > t:
+                out.append((t, bs))
+            t, k = max(t, be), k + 1
+        if not keep and t < e:
+            out.append((t, e))
+    return out
+
+
+def _seconds(spans) -> float:
+    return sum(e - s for s, e in spans) / 1e9
+
+
 def _label(host, t) -> str:
     """The innermost benchmark span (other than the window) covering t."""
     best = None
@@ -87,19 +125,29 @@ def _label(host, t) -> str:
 
 def reduce(events: dict, top: int = 10) -> dict:
     """Busy and idle seconds of the traced window (averaged over the chips
-    traced), device seconds and executions per engine program, and the
-    breakdown: top device operations by time, longest idle gaps by the
-    host span they fell in."""
+    traced), device seconds and executions per engine program, seconds of
+    collective operations inside the engine's programs and the part of them
+    in which no other operation ran on the chip (both averaged over the
+    chips), and the breakdown: top device operations by time, longest idle
+    gaps by the host span they fell in."""
     windows = [(s, e) for s, e, n in events["host"] if n == WINDOW]
     if not windows or not events["ops"]:
         raise ValueError("trace has no bench.window span or no device operations")
     lo, hi = windows[0]
     window_s = (hi - lo) / 1e9
     busy, gaps, op_time = [], [], {}
+    coll_s = alone_s = 0.0
     for plane, ops in events["ops"].items():
         ops = _clip(ops, lo, hi)
         spans = _union(ops)
-        busy.append(sum(e - s for s, e in spans) / 1e9)
+        busy.append(_seconds(spans))
+        engine = _union(m for m in _clip(events["modules"].get(plane, []), lo, hi)
+                        if program_of(m[2]))
+        coll = _cut(_union(o for o in ops if COLLECTIVE.match(o[2])), engine, keep=True)
+        other = _union(o for o in ops if not COLLECTIVE.match(o[2])
+                       and not CONTAINER.match(o[2]))
+        coll_s += _seconds(coll)
+        alone_s += _seconds(_cut(coll, other, keep=False))
         edges = [lo] + [x for span in spans for x in span] + [hi]
         for a, b in zip(edges[::2], edges[1::2]):
             if b > a:
@@ -125,6 +173,8 @@ def reduce(events: dict, top: int = 10) -> dict:
         "window_s": window_s,
         "busy_s": sum(busy) / len(busy),
         "programs": programs,
+        "collective_s": coll_s / n_chips,
+        "collective_alone_s": alone_s / n_chips,
         "device_ops": sorted(([n, t] for n, t in op_time.items()),
                              key=lambda x: -x[1])[:top],
         "idle_gaps": [[_label(events["host"], mid), g / 1e9] for g, mid in gaps[:top]],

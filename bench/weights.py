@@ -1,61 +1,28 @@
-"""Seeded weights for a llama-style decoder, made on the device in one jitted
-call and in the dtype the configuration serves.
+"""Seeded weights, made on the device in one jitted call and in the dtype the
+configuration serves, and where the benchmark keeps them.
 
 The benchmark owns the draw: the engine under test and the plain reference
 are both handed what :func:`make` returns for a seed, so the reference takes
-no weights from the program.  :func:`program_tree` only rearranges the
-arrays into the parameter tree the engine reads.
+no weights from the program.  The architecture's module
+(``bench/arch/<name>.py``) gives the draw itself, what each leaf looks like
+to the engine, and the axis of each leaf that a cell of several chips
+splits.
+
+A cell on one chip draws every leaf whole on its device.  A cell on several
+draws each leaf already split over its chips along the architecture's
+``SPLIT_AXIS`` (whole where that axis does not divide by the chip count),
+so that no chip ever holds more than its share; with JAX's partitionable
+threefry (on by default) the values are those of the one-device draw, bit
+for bit.  The reference then takes one layer at a time whole onto every
+chip (:meth:`Place.layer`).
 """
 
 from __future__ import annotations
 
-import functools
-from typing import NamedTuple
-
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-
-class Dims(NamedTuple):
-    """Shapes and scalars of one llama-style configuration (hashable, so it
-    can be a static argument of a jitted function)."""
-
-    layers: int
-    d_model: int
-    heads: int
-    kv_heads: int
-    head_dim: int
-    d_ff: int
-    vocab: int
-    padded_vocab: int
-    emb_scale: float
-    residual_scale: float
-    logit_scale: float
-    norm_eps: float
-    rope_theta: float
-
-
-#: per-layer leaves, stacked on a leading layer axis
-LAYER_KEYS = ("ln1", "wq", "wk", "wv", "wo", "ln2", "w_gate", "w_up", "w_down")
-NORM_KEYS = ("ln1", "ln2", "final_norm")
-
-
-def shapes(m: Dims) -> dict[str, tuple[int, ...]]:
-    L, d, hd = m.layers, m.d_model, m.head_dim
-    return {
-        "embed": (m.padded_vocab, d),
-        "ln1": (L, d),
-        "wq": (L, d, m.heads * hd),
-        "wk": (L, d, m.kv_heads * hd),
-        "wv": (L, d, m.kv_heads * hd),
-        "wo": (L, m.heads * hd, d),
-        "ln2": (L, d),
-        "w_gate": (L, d, m.d_ff),
-        "w_up": (L, d, m.d_ff),
-        "w_down": (L, m.d_ff, d),
-        "final_norm": (d,),
-    }
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 
 def prng_key(seed: int) -> jax.Array:
@@ -64,36 +31,54 @@ def prng_key(seed: int) -> jax.Array:
     return jax.random.PRNGKey(word & 0x7FFFFFFF)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 2, 3))
-def _draw(m: Dims, key: jax.Array, embed_std: float, dtype: str) -> dict:
-    dt = jnp.dtype(dtype)
-    out = {}
-    for i, (name, shape) in enumerate(sorted(shapes(m).items())):
-        if name in NORM_KEYS:
-            out[name] = jnp.ones(shape, dt)
-            continue
-        std = embed_std if name == "embed" else shape[-2] ** -0.5
-        draw = jax.random.normal(jax.random.fold_in(key, i), shape, dt)
-        out[name] = draw * jnp.asarray(std, dt)
-    return out
+class Place:
+    """The devices of one cell: one (``mesh`` None; arrays stay where they
+    are made) or several, as a one-axis mesh ``("chips",)``."""
+
+    def __init__(self, devices=()):
+        self.mesh = Mesh(np.asarray(devices), ("chips",)) if len(devices) > 1 else None
+        if self.mesh is not None:
+            self._whole = NamedSharding(self.mesh, PartitionSpec())
+            self._take = jax.jit(
+                lambda w, i: {k: jax.lax.dynamic_slice_in_dim(a, i, 1) for k, a in w.items()},
+                out_shardings=self._whole)
+
+    @property
+    def chips(self) -> int:
+        return 1 if self.mesh is None else self.mesh.size
+
+    def split(self, shape: tuple[int, ...], axis: int) -> NamedSharding:
+        """A leaf of ``shape`` split over the chips along ``axis``, or whole
+        on each where that axis does not divide."""
+        if shape[axis] % self.chips:
+            return self._whole
+        spec = [None] * len(shape)
+        spec[axis] = "chips"
+        return NamedSharding(self.mesh, PartitionSpec(*spec))
+
+    def whole(self, a: jax.Array) -> jax.Array:
+        """``a`` whole on every chip."""
+        return a if self.mesh is None else jax.device_put(a, self._whole)
+
+    def layer(self, stacked: dict, i: int) -> tuple[dict, int]:
+        """Leaves stacked on a leading layer axis and the index of layer
+        ``i`` among what is returned: on one chip the leaves as they are,
+        on several layer ``i`` alone, whole on every chip."""
+        if self.mesh is None:
+            return stacked, i
+        return self._take(stacked, jnp.int32(i)), 0
 
 
-def make(m: Dims, seed: int, embed_std: float, dtype: str = "bfloat16") -> dict:
-    """Every weight of the configuration, drawn from ``seed``: embedding
-    rows at ``embed_std``, projections at 1/sqrt(fan-in), norm scales 1."""
-    return jax.block_until_ready(_draw(m, prng_key(seed), float(embed_std), dtype))
-
-
-def program_tree(w: dict) -> dict:
-    """The engine's parameter tree over the same arrays (no copy)."""
-    kern = lambda a: {"kernel": a}  # noqa: E731
-    return {
-        "embed": {"table": w["embed"]},
-        "blocks": {
-            "ln1": {"scale": w["ln1"]},
-            "attn": {k: kern(w[k]) for k in ("wq", "wk", "wv", "wo")},
-            "ln2": {"scale": w["ln2"]},
-            "ffn": {k: kern(w[k]) for k in ("w_gate", "w_up", "w_down")},
-        },
-        "final_norm": {"scale": w["final_norm"]},
-    }
+def make(arch, m, seed: int, embed_std: float, dtype: str = "bfloat16",
+         place: Place | None = None) -> dict:
+    """Every weight of the configuration, drawn from ``seed`` by
+    ``arch.draw``, on ``place`` (one device where None)."""
+    place = place or Place()
+    key, std = prng_key(seed), float(embed_std)
+    split = {}
+    if place.mesh is not None:
+        abstract = jax.eval_shape(lambda k: arch.draw(m, k, std, dtype), key)
+        split["out_shardings"] = {k: place.split(a.shape, arch.SPLIT_AXIS[k])
+                                  for k, a in abstract.items()}
+    fn = jax.jit(arch.draw, static_argnums=(0, 2, 3), **split)
+    return jax.block_until_ready(fn(m, key, std, dtype))

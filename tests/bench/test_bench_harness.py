@@ -13,6 +13,7 @@ import subprocess
 import sys
 import time
 from pathlib import Path
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
@@ -22,7 +23,8 @@ import pytest
 ROOT = Path(__file__).resolve().parents[2]
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
-from bench import harness, readers, reference, trace, traffic, weights, work  # noqa: E402
+from bench import harness, readers, trace, traffic, weights  # noqa: E402
+from bench.arch import llama  # noqa: E402
 
 TESTDATA = ROOT / "bench" / "testdata"
 SEED = 2**31 + 77  # wider than 32 bits, as the benchmark's seeds may be
@@ -96,6 +98,84 @@ def test_trace_reduction_gives_the_recorded_numbers(tiny_trace):
         assert out["programs"][prog]["device_s"] == pytest.approx(p["device_s"], rel=1e-9)
 
 
+def _fixture_rec(reduced: dict, chips: int) -> SimpleNamespace:
+    """A run record over the fixture's reduced trace: the tiny config, two
+    requests whose decode tokens and one first token fall in the stretch."""
+    from bench.client import Record
+    from bench.traffic import Req
+
+    cfg = json.loads((TESTDATA / "configs" / "tiny.json").read_text())
+    recs = []
+    for i, n in enumerate((10, 20)):
+        r = Record(Req([1] * n, 16, due=0.0), 0.0)
+        r.tokens = [0.5 + i + 0.1 * k for k in range(16)]
+        recs.append(r)
+    counters = {k: {c: 0 for c in harness.COUNTERS} for k in ("t0", "t1")}
+    return SimpleNamespace(
+        arch=llama, dims=llama.dims(harness.model_config(cfg)), peak=harness.peaks("TPU v5 lite"),
+        chips=chips, decode_steps=4, trace=reduced, trace_interval=(1.0, 3.0), records=recs,
+        counters=counters, prefix_tokens=0)
+
+
+def test_shares_of_a_peak_are_taken_over_the_cells_chips(tiny_trace):
+    """On the recorded trace: with one chip the roofline and MFU shares are
+    the work's least time at one chip's peaks over the recorded device
+    times; a cell of four chips reads a quarter of each."""
+    want = json.loads(TRACE_EXPECT.read_text())
+    reduced = trace.reduce(tiny_trace)
+    one, four = _fixture_rec(reduced, 1), _fixture_rec(reduced, 4)
+    flops, nbytes = readers.decode_work(one)
+    least = max(flops / 197e12, nbytes / 819e9)
+    assert readers.decode_roofline(one) == pytest.approx(
+        100 * least / want["programs"]["decode"]["device_s"], rel=1e-9)
+    pflops = readers.prefill_work(one)[0]
+    assert readers.mfu(one) == pytest.approx(
+        100 * (flops + pflops) / (want["window_s"] * 197e12), rel=1e-9)
+    spec = harness.Spec()
+    for name in ("decode_roofline.tput", "prefill_roofline.lat", "mfu.tput"):
+        a, b = spec.reader(name)(one), spec.reader(name)(four)
+        assert 0 < a < 100 and b == pytest.approx(a / 4, rel=1e-12), name
+    assert spec.reader("decode_step_ms.tput")(four) == spec.reader("decode_step_ms.tput")(one)
+
+
+def _op(start, end, name):
+    return (start * 1000, end * 1000, f"%{name} = f32[8]{{0}} {name.split('.')[0]}(...)")
+
+
+def test_collective_share_on_a_synthetic_op_list():
+    """Collectives counted by op name inside the engine's programs only,
+    averaged over the chips, over the programs' device time; the part
+    with no other op running excludes the loop that holds them."""
+    plane = [
+        _op(0, 100, "while.41"),                  # the scan's loop holds all below
+        _op(0, 20, "fusion.1"),
+        _op(20, 30, "all-gather.151"),            # alone: 10
+        _op(30, 40, "async-collective-start"),    # under a fusion from 35: 5 alone
+        _op(35, 50, "fusion.266"),
+        _op(50, 60, "all-reduce.9"),              # alone: 10
+        _op(60, 70, "async-collective-done.1"),   # alone: 10
+        _op(70, 100, "fusion.2"),
+        _op(110, 120, "all-to-all.7"),            # in no engine program: not counted
+    ]
+    idle = [_op(0, 100, "while.41"), _op(0, 100, "fusion.3")]
+    events = {
+        "ops": {"/device:TPU:0": plane, "/device:TPU:1": idle},
+        "modules": {"/device:TPU:0": [(0, 100_000, "jit__decode_scan(1)"),
+                                      (110_000, 120_000, "jit_broadcast(2)")],
+                    "/device:TPU:1": [(0, 100_000, "jit__decode_scan(1)")]},
+        "host": [(0, 200_000, trace.WINDOW)],
+    }
+    out = trace.reduce(events)
+    assert out["collective_s"] == pytest.approx(40e-6 / 2)
+    assert out["collective_alone_s"] == pytest.approx(35e-6 / 2)
+    assert out["programs"]["decode"]["device_s"] == pytest.approx(100e-6)
+    rec = SimpleNamespace(trace=out)
+    read = harness.Spec().reader("collective_share.mesh")
+    assert read(rec) == pytest.approx(20.0)
+    assert read(SimpleNamespace(trace=dict(out, collective_s=0.0))) is None
+    assert read(SimpleNamespace(trace=None)) is None
+
+
 # ----------------------------------------------------------------- work --
 
 @pytest.mark.parametrize("name,per_layer,vocab_d", [
@@ -104,23 +184,23 @@ def test_trace_reduction_gives_the_recorded_numbers(tiny_trace):
     ("granite-8b-l18", 4096 * 128 * (64 + 16) + 3 * 4096 * 14336, 49152 * 4096),
 ])
 def test_work_counts_match_hand_counts(name, per_layer, vocab_d):
-    m = harness.dims(harness.model_config(_config(name)))
-    assert work.linear_params(m) == m.layers * per_layer
+    m = llama.dims(harness.model_config(_config(name)))
+    assert llama.linear_params(m) == m.layers * per_layer
     norms = (2 * m.layers + 1) * m.d_model
-    assert work.weight_bytes(m) == 2 * (m.layers * per_layer + vocab_d + norms)
+    assert llama.weight_bytes(m) == 2 * (m.layers * per_layer + vocab_d + norms)
     kv = {"minicpm-2b": 2 * 2 * 40 * 36 * 64, "granite-8b-l18": 2 * 2 * 18 * 8 * 128}[name]
-    assert work.kv_bytes_per_pos(m) == kv
+    assert llama.kv_bytes_per_pos(m) == kv
     # two decode steps making tokens at positions 10 and 20
-    flops, nbytes = work.decode(m, [10, 20], steps=2)
+    flops, nbytes = llama.decode_work(m, [10, 20], steps=2)
     att = 4 * m.layers * m.heads * m.head_dim * (11 + 21)
     assert flops == 2 * (2 * m.layers * per_layer) + att + 2 * 2 * m.d_model * m.vocab
-    assert nbytes == 2 * work.weight_bytes(m) + kv * 32
+    assert nbytes == 2 * llama.weight_bytes(m) + kv * 32
     # one prompt of 5 new tokens: 1 + 2 + ... + 5 keys
-    flops, nbytes = work.prefill(m, 5, [5], dispatches=1)
+    flops, nbytes = llama.prefill_work(m, 5, [5], dispatches=1)
     assert flops == 5 * 2 * m.layers * per_layer + 4 * m.layers * m.heads * m.head_dim * 15 \
         + 2 * m.d_model * m.vocab
-    peak = harness.peaks("TPU v5 lite")
-    assert work.least_seconds(flops, nbytes, peak) == max(
+    rec = SimpleNamespace(peak=harness.peaks("TPU v5 lite"), chips=1)
+    assert readers.least_seconds(rec, flops, nbytes) == max(
         flops / 197e12, nbytes / 819e9)
 
 
@@ -146,14 +226,39 @@ def test_cells_configs_and_metrics_are_found_by_name():
         assert "setup_s" in e2e and len(e2e) >= 2
 
 
+@pytest.mark.parametrize("root", [ROOT / "bench", TESTDATA])
+def test_every_configuration_names_an_architecture_that_loads(root):
+    spec = harness.Spec(root.parent if root.name == "bench" else root, root)
+    for c in spec.data["configs"]:
+        cfg = spec.config(c["name"])
+        mod = spec.arch(cfg["architecture"])
+        for fn in ("model_config", "dims", "draw", "program_tree", "logit_gaps",
+                   "decode_work", "prefill_work"):
+            assert callable(getattr(mod, fn)), (cfg["architecture"], fn)
+        m = mod.dims(mod.model_config(cfg))
+        assert set(mod.SPLIT_AXIS) == set(jax.eval_shape(
+            lambda k: mod.draw(m, k, 0.02, "bfloat16"), jax.random.PRNGKey(0)))
+    with pytest.raises(KeyError, match="no architecture"):
+        spec.arch("no-such-architecture")
+    with pytest.raises(KeyError, match="no architecture"):
+        harness.model_config(dict(cfg, architecture="no-such-architecture"))
+
+
 def test_a_cell_added_as_files_and_entries_is_found(tmp_path):
-    """A new configuration, mix and cell: data files and BENCHMARK.json
-    entries only, no code."""
+    """A new configuration of a new architecture, mix and cell: the
+    architecture's module, data files and BENCHMARK.json entries only, no
+    edit of a file the benchmark has."""
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
-    (tmp_path / "configs").mkdir()
-    (tmp_path / "traffic").mkdir()
+    for d in ("configs", "traffic", "arch"):
+        (tmp_path / d).mkdir()
+    # an architecture of its own: llama's functions with its own work count
+    (tmp_path / "arch" / "probe.py").write_text(
+        "from bench.arch.llama import *  # noqa: F403\n"
+        "from bench.arch.llama import logit_gaps, model_config  # noqa: F401\n\n\n"
+        "def decode_work(m, positions, steps):\n"
+        "    return 1e12, 0.0\n")
     cfg = _small("granite-8b-l18")
-    cfg["name"] = "granite-small"
+    cfg.update(name="granite-small", architecture="probe")
     (tmp_path / "configs" / "granite-small.json").write_text(json.dumps(cfg))
     mix = json.loads((ROOT / "bench" / "traffic" / "agent-32c.json").read_text())
     (tmp_path / "traffic" / "burst.json").write_text(json.dumps(dict(mix, clients=3)))
@@ -168,6 +273,15 @@ def test_a_cell_added_as_files_and_entries_is_found(tmp_path):
     spec = harness.Spec(tmp_path, tmp_path)
     assert spec.cell("granite-small.burst")["traffic"] == "burst"
     assert spec.config("granite-small")["model"]["hidden_size"] == 64
+    probe = spec.arch(spec.config("granite-small")["architecture"])
+    assert probe is not llama and probe.logit_gaps is llama.logit_gaps
+    assert probe.model_config(cfg).n_layers == 2
+    rec = SimpleNamespace(arch=probe, dims=probe.dims(probe.model_config(cfg)),
+                          peak={"bf16_flops_per_s": 1e14, "hbm_bytes_per_s": 1e12}, chips=1,
+                          decode_steps=4, trace={"programs": {"decode": {"count": 1,
+                                                                         "device_s": 0.02}}},
+                          trace_interval=(0.0, 1.0), records=[])
+    assert readers.decode_roofline(rec) == pytest.approx(50.0)
     got = {m["name"] for m in spec.metrics("granite-small.burst", False)}
     assert got == {"output_tokens_per_s", "setup_s"}
     got = {m["name"] for m in spec.metrics("granite-small.burst", True)}
@@ -236,9 +350,9 @@ def test_reference_matches_engine_prefill_and_cached_decode(name):
 
     cfg = _small(name)
     mc = harness.model_config(cfg)
-    m = harness.dims(mc)
-    w = weights.make(m, SEED, 0.02)
-    eng = Engine(mc, weights.program_tree(w),
+    m = llama.dims(mc)
+    w = weights.make(llama, m, SEED, 0.02)
+    eng = Engine(mc, llama.program_tree(w),
                  ServeConfig(max_batch=2, max_seq_len=32, prefill_buckets=(32,)))
     ex = eng.executor
     rng = np.random.default_rng(0)
@@ -249,7 +363,7 @@ def test_reference_matches_engine_prefill_and_cached_decode(name):
     last, caches = jax.jit(ex._prefill_batch)(
         ex.params, jnp.asarray(toks), jnp.asarray(lens), ex.caches,
         jnp.arange(2, dtype=jnp.int32), jnp.zeros(2, jnp.int32))
-    ref = reference.logits(m, w, toks)
+    ref = llama.logits(m, w, toks)
     scale = np.abs(ref).max()
     for r, n in enumerate(lens):
         got = np.asarray(last[r, :m.vocab], np.float32)
@@ -259,7 +373,7 @@ def test_reference_matches_engine_prefill_and_cached_decode(name):
                             caches, kernel=ex.kernel)
     toks2 = toks.copy()
     toks2[np.arange(2), lens] = nxt
-    ref2 = reference.logits(m, w, toks2)
+    ref2 = llama.logits(m, w, toks2)
     for r, n in enumerate(lens):
         got = np.asarray(dec[r, :m.vocab], np.float32)
         assert np.abs(got - ref2[r, n]).max() < 0.03 * scale
@@ -343,7 +457,8 @@ def test_a_traced_run_records_after_the_window(monkeypatch):
     monkeypatch.setattr(cl.Client, "submit", submit)
     monkeypatch.setattr(trace, "load", lambda path: {})
     monkeypatch.setattr(trace, "reduce", lambda ev: {
-        "window_s": 1.0, "busy_s": 0.5, "programs": {}, "device_ops": [], "idle_gaps": []})
+        "window_s": 1.0, "busy_s": 0.5, "programs": {}, "collective_s": 0.0,
+        "collective_alone_s": 0.0, "device_ops": [], "idle_gaps": []})
     # a CPU run has no chip and so no peak to share against
     monkeypatch.setattr(readers, "mfu", lambda rec: None)
     t_start = time.perf_counter()
@@ -368,12 +483,10 @@ def test_a_broken_timed_path_is_not_correct(fault, monkeypatch):
 # -------------------------------------------------------------- readers --
 
 def test_readers_on_a_synthetic_record():
-    from types import SimpleNamespace
-
     from bench.client import Record
     from bench.traffic import Req
 
-    m = harness.dims(harness.model_config(_config("granite-8b-l18")))
+    m = llama.dims(harness.model_config(_config("granite-8b-l18")))
     recs = []
     for i in range(10):
         r = Record(Req([1] * 100, 8, due=10.0 + i), 10.0 + i, submitted=10.0 + i + 0.001,
@@ -382,7 +495,8 @@ def test_readers_on_a_synthetic_record():
         r.done = r.tokens[-1]
         recs.append(r)
     rec = SimpleNamespace(
-        dims=m, peak=harness.peaks("TPU v5 lite"), decode_steps=4, window=(10.0, 20.0),
+        arch=llama, dims=m, peak=harness.peaks("TPU v5 lite"), chips=1, decode_steps=4,
+        window=(10.0, 20.0),
         requests=recs, records=recs, setup_s=12.5, open=True, prefix_tokens=0,
         counters={k: {c: 0 for c in harness.COUNTERS} for k in ("w0", "w1", "t0", "t1")},
         trace_interval=(10.0, 20.0),
