@@ -32,7 +32,8 @@ weights drawn from ``--seed``.  Every phase goes through the serving
   noise floor of bf16 computation.
 
 ``--four-chips`` runs only the mesh path: an engine with
-``shard_decode=True`` on the (4, 1) host mesh against a one-chip engine
+``shard_decode=True``, its parameters committed to the four chips, which
+it splits tensor parallel over (a (1, 4) mesh), against a one-chip engine
 in the same process; the KV pool and the parameters must span all four
 chips, the greedy tokens must be equal, and prefill logits agree within
 ``LOGIT_RTOL``.
@@ -346,6 +347,7 @@ def four_chips(cfg, seed):
 
     from repro.configs.base import ServeConfig
     from repro.serve import Engine
+    from repro.serve.cli import place_params
 
     rng = np.random.default_rng(seed)
     requests = make_requests(rng, cfg.vocab_size, N_REQUESTS // 2,
@@ -359,7 +361,10 @@ def four_chips(cfg, seed):
     del eng
     gc.collect()
 
-    eng = Engine(cfg, params, ServeConfig(**BASE, shard_decode=True))
+    sharded = ServeConfig(**BASE, shard_decode=True)
+    params = place_params(params, sharded)
+    eng = Engine(cfg, params, sharded)
+    del params
     ex = eng.executor
     print(f"four chips: mesh {dict(ex.mesh.shape)}")
     devices = set(jax.devices())

@@ -20,7 +20,7 @@ from repro import configs
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models import lm
 from repro.serve import Engine
-from repro.serve.cli import add_serving_args, config_from_args
+from repro.serve.cli import add_serving_args, config_from_args, place_params
 
 
 def stream_demo(eng, handles):
@@ -59,8 +59,9 @@ def main():
 
     enable_compile_cache()
     cfg = configs.get_config(args.arch, reduced=True)
-    params = lm.init_params(cfg, jax.random.PRNGKey(0))
-    eng = Engine(cfg, params, config_from_args(args, cfg))
+    serve_cfg = config_from_args(args, cfg)
+    params = place_params(lm.init_params(cfg, jax.random.PRNGKey(0)), serve_cfg)
+    eng = Engine(cfg, params, serve_cfg)
     print(f"serving {cfg.name} ({lm.count_params(cfg):,} params), "
           f"max_batch={args.max_batch}, policy={eng.executor.policy.name}, "
           f"kv_layout={eng.executor.kv_layout}, "

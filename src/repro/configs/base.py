@@ -450,14 +450,15 @@ class ServeConfig:
     # (see README "Async engine loop & mesh sharding").  Off by default:
     # the legacy loop runs byte-identical code.
     async_loop: bool = False
-    # --- mesh-sharded decode (distributed/sharding.py) ---
-    # Place params and KV caches with NamedSharding over a host mesh
-    # (data x model, launch/mesh.make_host_mesh) via ShardingRules /
-    # cache_shardings, so every prefill/extend/decode program compiles
-    # against sharding-annotated operands.  On a 1-device host this is
-    # the identity placement (token streams bit-identical, jit budget
-    # unchanged — both test-enforced); on a multi-device host the paged
-    # KV pools shard over kv_heads (TP) with the page table over batch.
+    # --- mesh-sharded serving (distributed/sharding.py) ---
+    # Tensor parallel over the devices the params are committed to: a
+    # (data 1, model n) mesh (launch/mesh.tensor_parallel_mesh), params
+    # and KV caches placed by ShardingRules / cache_shardings (heads, KV
+    # heads, FFN columns and the vocabulary split n ways; the paged pools
+    # over kv_heads, built in place), so every prefill/extend/decode
+    # program compiles against sharding-annotated operands.  Params on
+    # one device give the identity placement (token streams
+    # bit-identical, jit budget unchanged — both test-enforced).
     shard_decode: bool = False
     # Data-parallel replica fan-out (serve/router.py ReplicaRouter):
     # N independent engines behind one queue with least-loaded

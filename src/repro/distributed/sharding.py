@@ -20,6 +20,7 @@ on the multi-pod mesh):
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any
 
 import jax
@@ -198,3 +199,24 @@ def cache_shardings(
         return {k: _walk(abs_node[k], axes_node[k]) for k in abs_node}
 
     return _walk(abstract, axes_map)
+
+
+def committed_devices(tree: Any) -> list:
+    """The devices ``tree``'s arrays are committed to, in id order."""
+    devs = set()
+    for x in jax.tree.leaves(tree):
+        if isinstance(x, jax.Array):
+            devs |= x.sharding.device_set
+    return sorted(devs, key=lambda d: d.id)
+
+
+def bytes_per_device(tree: Any) -> int:
+    """The most bytes of ``tree``'s arrays that any one device holds."""
+    per: dict = {}
+    for x in jax.tree.leaves(tree):
+        if not isinstance(x, jax.Array):
+            continue
+        n = math.prod(x.sharding.shard_shape(x.shape)) * x.dtype.itemsize
+        for d in x.sharding.device_set:
+            per[d] = per.get(d, 0) + n
+    return max(per.values(), default=0)

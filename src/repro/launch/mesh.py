@@ -34,6 +34,17 @@ def make_host_mesh(model: int | None = None):
     return make_mesh((n // model, model), ("data", "model"))
 
 
+def tensor_parallel_mesh(devices):
+    """The serving mesh: (data 1, model n) over ``devices``, in device-id
+    order, so that heads, KV heads, FFN columns and the vocabulary split
+    n ways and no weight is gathered."""
+    devices = sorted(devices, key=lambda d: d.id)
+    return jax.make_mesh(
+        (1, len(devices)), ("data", "model"),
+        axis_types=(jax.sharding.AxisType.Auto,) * 2, devices=devices,
+    )
+
+
 def mesh_axis_names(mesh) -> tuple[str, ...]:
     return tuple(mesh.axis_names)
 

@@ -28,6 +28,7 @@ from repro.serve import Engine, ReplicaRouter
 from repro.serve.cli import (  # noqa: F401  (resolve_policy_arg re-export)
     add_serving_args,
     config_from_args,
+    place_params,
     resolve_policy_arg,
 )
 
@@ -43,8 +44,8 @@ def main():
 
     enable_compile_cache()
     cfg = configs.get_config(args.arch, reduced=not args.full_config)
-    params = lm.init_params(cfg, jax.random.PRNGKey(0))
     serve_cfg = config_from_args(args, cfg)
+    params = place_params(lm.init_params(cfg, jax.random.PRNGKey(0)), serve_cfg)
     # replicas > 1: the same request-lifecycle API, fronted by the
     # least-loaded data-parallel router (serve/router.py)
     eng = (

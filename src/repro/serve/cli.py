@@ -145,15 +145,32 @@ def add_serving_args(
                          "token streams stay bit-identical to the "
                          "synchronous loop (results surface one step late)")
     ap.add_argument("--shard-decode", action="store_true",
-                    help="place params and KV pools with NamedSharding "
-                         "over the host (data, model) mesh; the same "
-                         "len(buckets)+2 programs compile against sharded "
-                         "operands (single-device meshes are a no-op)")
+                    help="tensor parallel over the devices the params "
+                         "are committed to: params and KV pools placed "
+                         "with NamedSharding on a (data 1, model n) mesh; "
+                         "the same len(buckets)+2 programs compile against "
+                         "sharded operands (one device is a no-op)")
     ap.add_argument("--replicas", type=int, default=1,
                     help="data-parallel engines behind one ReplicaRouter "
                          "front door with least-loaded admission (each "
                          "replica owns its KV pool and jit caches)")
     return ap
+
+
+def place_params(params, serve_cfg: ServeConfig):
+    """The params as a serving entry point hands them to the engine: with
+    ``shard_decode``, committed to every device of the host, which the
+    engine then splits them tensor-parallel over."""
+    if not serve_cfg.shard_decode:
+        return params
+    import jax
+    import numpy as np
+
+    everywhere = jax.sharding.NamedSharding(
+        jax.sharding.Mesh(np.asarray(jax.devices()), ("devices",)),
+        jax.sharding.PartitionSpec(),
+    )
+    return jax.device_put(params, everywhere)
 
 
 def config_from_args(args: argparse.Namespace, model_cfg) -> ServeConfig:

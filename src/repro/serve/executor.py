@@ -37,6 +37,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig, ServeConfig
 from repro.core import precision as precision_lib
+from repro.distributed import sharding as sharding_lib
 from repro.models import lm
 from repro.serve import kv_cache
 from repro.serve.phases import NULL_TRACER
@@ -323,16 +324,18 @@ class ModelExecutor:
             cfg, sc, quantized=self.quant_cache, dtype=jnp.float32
         )
         self.kv_layout = self.cache_mgr.layout
-        self.caches = self.cache_mgr.init_device_caches()
         self.slots = [Slot() for _ in range(sc.max_batch)]
         #: pipelined loop (ServeConfig.async_loop): dispatch and collect
         #: interleave across steps, and the decode carry stays on device
         self.async_loop = bool(sc.async_loop)
 
-        # Mesh-sharded decode: place params and the KV pools with
-        # NamedSharding over a (data, model) host mesh so every
-        # prefill/extend/decode program compiles against sharding-
-        # annotated operands.  Committed input shardings propagate
+        # Mesh-sharded serving: tensor parallel over the devices the
+        # params are committed to, a (data 1, model n) mesh.  Params and
+        # the KV pools get NamedShardings from their logical axes (heads,
+        # KV heads, FFN columns and the vocabulary split n ways), so
+        # every prefill/extend/decode program compiles against sharding-
+        # annotated operands and GSPMD puts two all-reduces in a layer
+        # and gathers no weight.  Committed input shardings propagate
         # through the existing jitted programs — no new programs, so the
         # len(buckets)+2 budget holds (test-enforced).  The page table
         # keeps its sharding across host-side rebuilds via the manager's
@@ -343,18 +346,23 @@ class ModelExecutor:
         self.sharding_rules = None
         self._cache_out_sh = None
         self._rep_sh = None
+        replicated_leaves = 0
         if sc.shard_decode:
-            from repro.distributed import sharding as sharding_lib
-            from repro.launch.mesh import make_host_mesh
+            from repro.launch.mesh import tensor_parallel_mesh
 
-            self.mesh = make_host_mesh()
+            self.mesh = tensor_parallel_mesh(
+                sharding_lib.committed_devices(self.params)
+            )
             rules = sharding_lib.ShardingRules(self.mesh)
             self.sharding_rules = rules
             self.params = jax.device_put(
                 self.params, sharding_lib.param_shardings(rules, cfg, lm)
             )
+            replicated_leaves = len(rules.fallbacks)
             cache_sh = self.cache_mgr.device_shardings(rules)
-            self.caches = jax.device_put(self.caches, cache_sh)
+            # each pool leaf is made in place under its sharding: no
+            # device ever holds a whole pool
+            self.caches = self.cache_mgr.init_device_caches(cache_sh)
             table_sh = cache_sh.get("layers", {}).get("page_table")
             if table_sh is not None:
                 self.cache_mgr.table_sharding = table_sh
@@ -369,6 +377,8 @@ class ModelExecutor:
             self._rep_sh = jax.sharding.NamedSharding(
                 self.mesh, jax.sharding.PartitionSpec()
             )
+        else:
+            self.caches = self.cache_mgr.init_device_caches()
 
         # Device-resident decode carry (async loop): the scan's final
         # (token, position, active, budget) per slot, kept on device so
@@ -459,6 +469,17 @@ class ModelExecutor:
         # untraced hot loop is byte-for-byte the historical one.
         self.tracer = NULL_TRACER
         self.tel = {
+            # the placement, at set-up: the most bytes of params and of
+            # the KV cache tree on one device, and the param axes that
+            # fell back to replication because the mesh does not divide
+            # them
+            "param_bytes_per_chip": sharding_lib.bytes_per_device(
+                self.params
+            ),
+            "kv_pool_bytes_per_chip": sharding_lib.bytes_per_device(
+                self.caches
+            ),
+            "replicated_param_leaves": replicated_leaves,
             "tokens_generated": 0,
             "prefill_compiles": 0,
             "prefill_dispatches": 0,

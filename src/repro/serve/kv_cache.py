@@ -1102,16 +1102,27 @@ class CacheManager:
             dtype=self.dtype, quantized=self.quantized, **self._layout_kw(),
         )
 
-    def init_device_caches(self) -> PyTree:
-        return init_caches(
-            self.cfg, self.serve_cfg.max_batch, self.serve_cfg.max_seq_len,
-            dtype=self.dtype, quantized=self.quantized, **self._layout_kw(),
-        )
+    def init_device_caches(self, shardings: PyTree | None = None) -> PyTree:
+        """The zeroed device caches; with ``shardings`` (the tree
+        :meth:`device_shardings` gives) each leaf is made in place under
+        its sharding by one jitted fill, so no device holds more than its
+        share."""
+
+        def make():
+            return init_caches(
+                self.cfg, self.serve_cfg.max_batch,
+                self.serve_cfg.max_seq_len, dtype=self.dtype,
+                quantized=self.quantized, **self._layout_kw(),
+            )
+
+        if shardings is None:
+            return make()
+        return jax.jit(make, out_shardings=shardings)()
 
     def device_shardings(self, rules) -> PyTree:
         """NamedSharding tree matching :meth:`init_device_caches` for the
         given :class:`~repro.distributed.sharding.ShardingRules` — the
-        executor device_puts the live caches onto it when
+        executor builds the live caches under it when
         ``ServeConfig.shard_decode`` is on, and stores the page_table
         leaf in :attr:`table_sharding` so :meth:`write_table` rebuilds
         land on the same placement."""
@@ -1925,7 +1936,10 @@ class CacheManager:
             assert ref[page] > 0 or page in self._cached, (
                 f"pending swap-in targets page {page}, neither live nor cached"
             )
+        # a spill's slot holds its key, or its key was fetched back before
+        # the spill flushed: flush_swaps lands spills before swap-ins, so
+        # the rows pass device -> host -> device in one flush
         for page, host in self._pending_spills:
-            assert host in self._host_key, (
+            assert host in self._host_key or host in transit, (
                 f"pending spill targets unindexed host slot {host}"
             )

@@ -306,9 +306,11 @@ def test_fenced_tracer_with_async_loop_warns(cfg, params):
 
 
 def test_shard_decode_places_named_shardings(cfg, params):
-    """shard_decode commits params and KV pools to NamedSharding over
-    the host mesh; on a single-device mesh this must be a semantic
-    no-op (identical tokens) while every cache leaf is mesh-placed."""
+    """shard_decode commits params and KV pools to NamedSharding over a
+    tensor-parallel (data 1, model n) mesh of the devices the params are
+    committed to; params on one device give a one-device mesh, which
+    must be a semantic no-op (identical tokens) while every cache leaf
+    is mesh-placed."""
     kw = dict(kv_layout="paged", kv_page_size=8)
     sync, _ = _generate_tokens(cfg, params, _serve(**kw))
     sharded, eng = _generate_tokens(
@@ -316,6 +318,9 @@ def test_shard_decode_places_named_shardings(cfg, params):
     )
     assert sharded == sync
     assert eng.executor.mesh is not None
+    assert dict(eng.executor.mesh.shape) == {"data": 1, "model": 1}
+    assert set(eng.executor.mesh.devices.flat) == {jax.devices()[0]}
+    assert eng.telemetry["replicated_param_leaves"] == 0
     for leaf in jax.tree.leaves(eng.executor.caches):
         assert isinstance(leaf.sharding, NamedSharding)
     for leaf in jax.tree.leaves(eng.executor.params):

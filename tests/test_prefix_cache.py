@@ -185,6 +185,14 @@ def test_manager_invariants_with_victim_tier(pool, page_size, seed, host):
     _trace_manager(pool, page_size, seed, host_pages=host)
 
 
+def test_victim_tier_fetches_a_key_back_before_its_spill_flushes():
+    """A trace whose admission fetches back a chain key whose spill has
+    not flushed yet, then evicts for the fetched page: the spill's slot is
+    then in transit (its swap-in pending) rather than indexed, which the
+    invariants allow because spills land before swap-ins."""
+    _trace_manager(9, 2, 5244, host_pages=2)
+
+
 def test_invariant_checker_catches_corruption():
     """The checker itself must fail loudly on a corrupted pool (otherwise
     the property test above proves nothing)."""

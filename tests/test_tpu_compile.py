@@ -203,3 +203,86 @@ def test_kv_pool_is_updated_in_place(one_chip, monkeypatch, name):
     for program in (decode, extend):
         assert _pool_producers(program.as_text(), pool_shapes) == []
         assert program.memory_analysis().alias_size_in_bytes >= pool_bytes
+
+
+def test_tensor_parallel_decode_on_a_v5e_2x2(topo, monkeypatch):
+    """granite-8b's decode scan as ``shard_decode`` places it over the four
+    chips of a described v5e:2x2 (tensor parallel, mesh data 1 x model 4),
+    at 2 of its 36 layers: each chip's operands are a quarter of the
+    weights and of the KV pool, no all-gather returns a weight or one layer
+    of it, and the layer loop holds two all-reduces (attention output, MLP
+    down projection)."""
+    from bench import harness
+    from repro.distributed import sharding
+    from repro.launch.mesh import tensor_parallel_mesh
+    from repro.models import lm
+    from repro.serve import kv_cache
+    from repro.serve.executor import ModelExecutor
+
+    cfg = json.loads((CONFIGS / "granite-8b.json").read_text())
+    cfg["model"]["num_hidden_layers"] = 2
+    mc = harness.model_config(cfg)
+    sc = harness.serve_config(cfg, shard_decode=False)
+    monkeypatch.setattr(
+        kv_cache.CacheManager, "init_device_caches",
+        lambda self, shardings=None: self._abstract(),
+    )
+    ex = ModelExecutor(mc, lm.abstract_params(mc), sc)
+    mesh = tensor_parallel_mesh(topo.devices)
+    rules = sharding.ShardingRules(mesh)
+    psh = sharding.param_shardings(rules, mc, lm)
+    csh = ex.cache_mgr.device_shardings(rules)
+    assert rules.fallbacks == []
+    rep = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
+
+    def placed(tree, shardings):
+        return jax.tree.map(
+            lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+            tree, shardings,
+        )
+
+    params, caches = placed(ex.params, psh), placed(ex.caches, csh)
+    nb, steps, i32, f32 = sc.max_batch, sc.decode_steps, jnp.int32, jnp.float32
+
+    def arg(shape, dtype=i32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=rep)
+
+    decode = jax.jit(
+        ex._decode_scan, out_shardings=(rep,) * 6 + (csh,), donate_argnums=12
+    ).lower(
+        params, arg((nb,)), arg((nb,)), arg((nb,), bool), arg((nb,)),
+        arg((nb,)), arg((nb,), f32), arg((nb,)), arg((nb,), f32),
+        arg((nb,)), arg((steps, nb)), arg((nb,)), caches,
+        arg((2,), jnp.uint32),
+    ).compile()
+
+    def nbytes(tree):
+        return sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in jax.tree.leaves(tree))
+
+    whole = nbytes(params) + nbytes(caches)
+    assert decode.memory_analysis().argument_size_in_bytes < whole / 3
+
+    hlo = decode.as_text()
+    comps, cur = {}, None
+    for line in hlo.splitlines():
+        head = _COMPUTATION.match(line)
+        if head:
+            cur = comps.setdefault(head.group(1), [])
+        elif cur is not None:
+            cur.append(line)
+    weights = {tuple(s) for a in jax.tree.leaves(params)
+               for s in (a.shape, a.shape[1:]) if len(s) >= 2}
+    gathered = {
+        tuple(int(x) for x in m.group(1).split(",") if x)
+        for m in re.finditer(r"= \w+\[([\d,]*)\]\S* all-gather(?:-start)?\(", hlo)
+    }
+    assert not gathered & weights, sorted(gathered & weights)
+    loops = [comps[b] for b in set(re.findall(r"body=%([^,\s]+)", hlo))]
+    layer_loops = [
+        body for body in loops
+        if not any(" while(" in line for line in body)
+        and any(re.search(r" all-reduce(-start)?\(", line) for line in body)
+    ]
+    assert len(layer_loops) == 1
+    reduces = [line for line in layer_loops[0] if re.search(r" all-reduce(-start)?\(", line)]
+    assert len(reduces) == 2, reduces
